@@ -26,12 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# jax.shard_map only exists on newer jax; fall back to the experimental home
-# (same callable) so this module works across the toolchain versions in use.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:                       # pragma: no cover - version dep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.kvcache import MLACache, sink_patched_content
 from repro.kernels.mla_decode import ref as mla_ref
 
@@ -79,7 +73,7 @@ def mla_decode_shard_map(
             block_n=block_n, fmt=fmt)
         return o
 
-    f = _shard_map(
+    f = jax.shard_map(
         local_attn,
         mesh=mesh,
         in_specs=(P(dpa, "model", None), P(dpa, "model", None), P(dpa, "model"),
@@ -125,7 +119,7 @@ def mla_append_shard_map(mesh, dp_axes, cache: MLACache, cache_cfg,
         def local_append(cache, c_kv, k_r):
             return mla_append(cache, cache_cfg, c_kv, k_r)
 
-        f = _shard_map(
+        f = jax.shard_map(
             local_append, mesh=mesh,
             in_specs=(cache_specs, P(dpa, None), P(dpa, None)),
             out_specs=cache_specs)
@@ -134,7 +128,7 @@ def mla_append_shard_map(mesh, dp_axes, cache: MLACache, cache_cfg,
     def local_append_gated(cache, c_kv, k_r, act):
         return mla_append(cache, cache_cfg, c_kv, k_r, active=act)
 
-    f = _shard_map(
+    f = jax.shard_map(
         local_append_gated, mesh=mesh,
         in_specs=(cache_specs, P(dpa, None), P(dpa, None), P(dpa)),
         out_specs=cache_specs)

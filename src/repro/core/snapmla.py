@@ -33,9 +33,10 @@ class SnapMLAConfig:
     mla: mla_lib.MLAConfig
     cache: CacheConfig = CacheConfig()
     # decode-attention backend (kernels/mla_decode/backends.py): True = the
-    # Pallas split-KV kernels (interpret on CPU), False = the jnp ref twins
+    # Pallas split-KV kernels, False = the jnp ref twins; interpret None =
+    # interpreted on CPU, compiled on TPU (runtime.platform.resolve_interpret)
     use_kernel: bool = True
-    interpret: bool = True
+    interpret: bool | None = None
     # split-KV (flash-decoding) sequence parallelism for the decode kernel:
     # None or 0 = autotuner profile with the context-length heuristic as
     # fallback (ops.resolve_num_splits), 1 = always single-pass (bit-exact

@@ -19,6 +19,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quant
+from repro.runtime.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -116,7 +117,7 @@ def gqa_decode_pallas(
     window: int = 0,
     block_n: int = 128,
     fmt: str = "fp8_e4m3",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, H, dh = q.shape
     N, Hkv = k8.shape[1], k8.shape[2]
@@ -150,5 +151,5 @@ def gqa_decode_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(positions, q, k8, v8, k_scale, v_scale, slot_pos)

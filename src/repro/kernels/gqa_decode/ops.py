@@ -21,7 +21,7 @@ def gqa_decode(
     block_n: int = 128,
     fmt: str = "fp8_e4m3",
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     N = cache.k.shape[1]
     pad = (-N) % block_n

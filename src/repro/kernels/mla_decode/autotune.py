@@ -312,7 +312,8 @@ def measure_split_sweep(capacity: int, block_n: int, batch: int,
                         *, d_c: int = 64, d_r: int = 16, heads: int = 8,
                         fmt: str = "fp8_e4m3", fill: float = 0.75,
                         iters: int = 3, profile: SplitProfile | None = None,
-                        layout: str = "contiguous", interpret: bool = True,
+                        layout: str = "contiguous",
+                        interpret: bool | None = None,
                         rescale: str = "fma", timer=None) -> dict[int, float]:
     """Time the real split-KV kernel over the candidate split counts and
     record the winner into ``profile`` (default: the singleton) under
@@ -435,13 +436,10 @@ def measure_config_sweep(capacity: int, batch: int,
     batch, layout) and ``lookup_config`` can pick the joint winner.
 
     ``interpret=None`` resolves to COMPILED measurement on TPU (interpret
-    elsewhere) — production shapes should be timed as the hardware runs
-    them, not through the interpreter. ``timer`` here takes
+    on CPU; ``runtime.platform.resolve_interpret``) — production shapes
+    should be timed as the hardware runs them. ``timer`` here takes
     ``timer(block_n, num_splits, run)`` (tests inject a fixed 2D grid via
     ``synthetic_timer_2d``). Returns {(block_n, num_splits): us}."""
-    if interpret is None:
-        import jax
-        interpret = jax.default_backend() != "tpu"
     if block_ns is None:
         block_ns = (candidate_block_ns(capacity) if layout == "contiguous"
                     else [block_ns_for_paged(capacity)])

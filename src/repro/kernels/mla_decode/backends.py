@@ -69,7 +69,8 @@ class BackendConfig:
     backend. ``num_splits`` None/0 = autotuner profile -> heuristic;
     ``block_n`` 0 = joint 2D (num_splits, block_n) plan from the v2 profile
     (contiguous caches only — paged block_n is structurally the page size);
-    ``interpret`` None = interpret on CPU, compiled on TPU; ``rescale``
+    ``interpret`` None = interpreted on CPU, compiled on TPU (any other
+    backend raises — ``runtime.platform.resolve_interpret``); ``rescale``
     "fma" = the exact per-block FMA rescale, "amla" = the AMLA exponent-add
     (combine-free split-KV emission) fast path."""
 
@@ -79,11 +80,6 @@ class BackendConfig:
     num_splits: int | None = None
     interpret: bool | None = None
     rescale: str = "fma"
-
-    def resolved_interpret(self) -> bool:
-        if self.interpret is None:
-            return jax.default_backend() != "tpu"
-        return self.interpret
 
 
 def _split_plan(cfg: BackendConfig, capacity: int, batch: int,
@@ -224,8 +220,7 @@ def _pallas_decode(q: DecodeQuery, cache: MLACache, cfg: BackendConfig,
     o, _lse = _ops.snapmla_decode(
         q.q_c8, q.q_r, q.sigma_q, cache, softmax_scale=cfg.softmax_scale,
         block_n=plan.block_n, fmt=cfg.fmt, num_splits=plan.num_splits,
-        use_kernel=True, interpret=cfg.resolved_interpret(),
-        rescale=cfg.rescale)
+        use_kernel=True, interpret=cfg.interpret, rescale=cfg.rescale)
     return o
 
 
@@ -234,7 +229,7 @@ def _pallas_paged_decode(q: DecodeQuery, pool: PagedMLAPool,
     o, _lse = _ops.snapmla_decode_paged(
         q.q_c8, q.q_r, q.sigma_q, pool, softmax_scale=cfg.softmax_scale,
         fmt=cfg.fmt, num_splits=cfg.num_splits, use_kernel=True,
-        interpret=cfg.resolved_interpret(), rescale=cfg.rescale)
+        interpret=cfg.interpret, rescale=cfg.rescale)
     return o
 
 
@@ -269,10 +264,13 @@ register(DecodeBackend("shard_map", "contiguous", "shard_map",
 # analytic dispatch cost (telemetry annotation; see obs/)
 # ---------------------------------------------------------------------------
 
-# v5e hardware constants (same figures as benchmarks/kernel_perf.py — pure
-# modeled numbers, deterministic on any machine)
-_V5E_HBM = 819e9          # bytes/s
-_V5E_BF16 = 197e12        # FLOP/s
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``: (HBM
+# bytes/s, bf16 FLOP/s). TPU v5e: 819 GB/s HBM, 197 TFLOP/s bf16 (Google
+# Cloud TPU documentation, "TPU v5e"). A device not listed here gets no
+# modeled time.
+PEAKS: dict[str, tuple[float, float]] = {
+    "TPU v5 lite": (819e9, 197e12),
+}
 
 
 def token_cost(fmt: str, d_c: int, d_r: int, heads: int
@@ -290,7 +288,7 @@ def token_cost(fmt: str, d_c: int, d_r: int, heads: int
 
 def dispatch_cost(backend: "DecodeBackend | str", *, tokens_visited: int,
                   tokens_full: int, heads: int, d_c: int, d_r: int,
-                  fmt: str) -> dict:
+                  fmt: str, device_kind: str | None = None) -> dict:
     """Analytic bytes/FLOPs annotation for ONE decode dispatch.
 
     ``tokens_visited`` is the KV-token work the split-KV early exit
@@ -301,7 +299,9 @@ def dispatch_cost(backend: "DecodeBackend | str", *, tokens_visited: int,
     modeled traffic is the full sweep — the annotation makes that
     structural difference visible per step. ``achieved_fraction`` is
     roofline-minimum bytes over modeled bytes: 1.0 = the dispatch streams
-    exactly the live context, lower = dead traffic."""
+    exactly the live context, lower = dead traffic. ``t_model_us`` is the
+    roofline time on ``device_kind``'s published peaks (``PEAKS``), None
+    for a device without an entry."""
     b = get_backend(backend) if isinstance(backend, str) else backend
     bytes_tok, flops_tok = token_cost(fmt, d_c, d_r, heads)
     streamed = tokens_full if (b.kind == "ref" and b.layout == "paged") \
@@ -310,7 +310,9 @@ def dispatch_cost(backend: "DecodeBackend | str", *, tokens_visited: int,
     model_bytes = streamed * bytes_tok
     min_bytes = tokens_visited * bytes_tok
     flops = tokens_visited * flops_tok
-    t_model_s = max(model_bytes / _V5E_HBM, flops / _V5E_BF16)
+    peaks = PEAKS.get(device_kind or "")
+    t_model_us = (max(model_bytes / peaks[0], flops / peaks[1]) * 1e6
+                  if peaks else None)
     return {
         "backend": b.name,
         "bytes": model_bytes,
@@ -318,7 +320,7 @@ def dispatch_cost(backend: "DecodeBackend | str", *, tokens_visited: int,
         "flops": flops,
         "achieved_fraction": (min_bytes / model_bytes
                               if model_bytes else 1.0),
-        "t_model_us": t_model_s * 1e6,
+        "t_model_us": t_model_us,
     }
 
 

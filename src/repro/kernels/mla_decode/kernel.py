@@ -82,6 +82,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import quant
 from repro.kernels.mla_decode import amla
+from repro.runtime.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -110,6 +111,8 @@ def _block_pipeline(qc, qr, sq, c, r, sk, tok0, seq_len,
     Shared verbatim between the single-pass, split-KV, and paged kernels so
     their per-block arithmetic is bit-identical. ``tok0`` is the absolute
     token index of the block's first entry; state is carried in VMEM scratch.
+    ``sq`` is the per-row query scale ``[rows]``; ``sk`` the block's
+    per-token key scales as a ``[1, bn]`` row.
 
     ``seq_len`` is either a scalar (every query row sees the same KV prefix —
     the decode case) or a ``[rows, 1]`` per-row limit (the ``q_len > 1``
@@ -142,7 +145,7 @@ def _block_pipeline(qc, qr, sq, c, r, sk, tok0, seq_len,
                             preferred_element_type=jnp.float32)
     s += jax.lax.dot_general(qr, r, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    s = s * (sq[:, None] * sk[None, :]) * softmax_scale            # [H, bn]
+    s = s * (sq[:, None] * sk) * softmax_scale                     # [H, bn]
 
     tok = tok0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     valid = tok < seq_len
@@ -156,7 +159,7 @@ def _block_pipeline(qc, qr, sq, c, r, sk, tok0, seq_len,
                             jnp.ceil(jnp.max(s, axis=-1) * amla.LOG2E))
         e = jnp.exp(s - (i_new * amla.LN2)[:, None])
         e = jnp.where(valid, e, 0.0)
-        p_fused = e * sk[None, :]
+        p_fused = e * sk
         p8, e_new = amla.quantize_block_pow2(p_fused, fmt, qmax)
         if row_guard:
             e_new = jnp.where(row_live, e_new, e_prev)
@@ -183,7 +186,7 @@ def _block_pipeline(qc, qr, sq, c, r, sk, tok0, seq_len,
     e = jnp.where(valid, e, 0.0)
 
     # --- Key Step 2: scale fusion + block-wise dynamic P quantization -----
-    p_fused = e * sk[None, :]
+    p_fused = e * sk
     p8, sp_new = _quantize_block(p_fused, fmt, qmax)
     if row_guard:
         sp_new = jnp.where(row_live, sp_new, sp_prev)
@@ -210,13 +213,13 @@ def _mla_decode_kernel(
     # inputs (VMEM blocks)
     q_c_ref,                # [1, H, d_c]  storage dtype
     q_r_ref,                # [1, H, d_r]  f32 (pre-divided by sigma_q)
-    sigma_q_ref,            # [1, H]       f32
-    content_ref,            # [1, bn, d_c] storage dtype (or [bn, d_c] paged)
+    sigma_q_ref,            # [1, 1, H]    f32
+    content_ref,            # [1, bn, d_c] storage dtype
     rope_ref,               # [1, bn, d_r] f32/bf16 (pre-divided by sigma_k)
-    sigma_k_ref,            # [1, bn]      f32
+    sigma_k_ref,            # [1, 1, bn]   f32
     # outputs
     o_ref,                  # [1, H, d_c]  f32
-    lse_ref,                # [1, H]       f32
+    lse_ref,                # [1, 1, H]    f32
     # scratch
     m_ref, l_ref, sp_ref,   # [H]
     acc_ref,                # [H, d_c]
@@ -225,7 +228,6 @@ def _mla_decode_kernel(
     block_n: int,
     fmt: str,
     qmax: float,
-    paged: bool,
     rescale: str = "fma",
 ):
     b = pl.program_id(0)
@@ -238,15 +240,10 @@ def _mla_decode_kernel(
 
     qc = q_c_ref[0].astype(jnp.float32)              # [H, d_c]
     qr = q_r_ref[0].astype(jnp.float32)              # [H, d_r]
-    sq = sigma_q_ref[0].astype(jnp.float32)          # [H]
-    if paged:
-        c = content_ref[...].astype(jnp.float32)     # [bn, d_c]
-        r = rope_ref[...].astype(jnp.float32)        # [bn, d_r]
-        sk = sigma_k_ref[...].astype(jnp.float32)    # [bn]
-    else:
-        c = content_ref[0].astype(jnp.float32)
-        r = rope_ref[0].astype(jnp.float32)
-        sk = sigma_k_ref[0].astype(jnp.float32)
+    sq = sigma_q_ref[0, 0].astype(jnp.float32)       # [H]
+    c = content_ref[0].astype(jnp.float32)           # [bn, d_c]
+    r = rope_ref[0].astype(jnp.float32)              # [bn, d_r]
+    sk = sigma_k_ref[0].astype(jnp.float32)          # [1, bn]
 
     _block_pipeline(qc, qr, sq, c, r, sk, j * block_n, seq_lens_ref[b],
                     m_ref, l_ref, sp_ref, acc_ref,
@@ -260,9 +257,20 @@ def _mla_decode_kernel(
         if rescale == "amla":
             # m_ref/sp_ref hold the integer exponents i and e: the scale-
             # carrying LSE is (i + e) * ln2 + log(l~)
-            lse_ref[0] = (m_ref[...] + sp_ref[...]) * amla.LN2 + jnp.log(l)
+            lse_ref[0, 0] = (m_ref[...] + sp_ref[...]) * amla.LN2 + jnp.log(l)
         else:
-            lse_ref[0] = m_ref[...] + jnp.log(sp_ref[...] * l)
+            lse_ref[0, 0] = m_ref[...] + jnp.log(sp_ref[...] * l)
+
+
+def unit_rows(x: jax.Array) -> jax.Array:
+    """``[B, X] -> [B, 1, X]``. The TPU lowering refuses a ``(1, X)`` block
+    of a ``[B, X]`` array (the last two block dims must tile by (8, 128) or
+    equal the array's), so per-row vectors — query scales, per-token key
+    scales, LSEs — cross the kernel boundary with a unit middle axis and ride
+    as ``(1, 1, X)`` blocks. A ``[n_pages, page]`` scale pool rides the same
+    way, one ``(1, 1, page)`` block per page-table entry; at page 128 the
+    reshape is a bitcast of the pool (no copy, no gather)."""
+    return x[:, None, :]
 
 
 def mla_decode_pallas(
@@ -277,7 +285,7 @@ def mla_decode_pallas(
     softmax_scale: float,
     block_n: int = 128,
     fmt: str = "fp8_e4m3",
-    interpret: bool = True,
+    interpret: bool | None = None,
     rescale: str = "fma",
 ) -> tuple[jax.Array, jax.Array]:
     """Contiguous-cache SnapMLA decode. Returns (o [B,H,d_c] f32, lse [B,H])."""
@@ -290,7 +298,7 @@ def mla_decode_pallas(
 
     kernel = functools.partial(
         _mla_decode_kernel, softmax_scale=softmax_scale, block_n=block_n,
-        fmt=fmt, qmax=qmax, paged=False, rescale=rescale)
+        fmt=fmt, qmax=qmax, rescale=rescale)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -298,31 +306,41 @@ def mla_decode_pallas(
         in_specs=[
             pl.BlockSpec((1, H, d_c), lambda b, j, sl: (b, 0, 0)),
             pl.BlockSpec((1, H, d_r), lambda b, j, sl: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j, sl: (b, 0)),
+            pl.BlockSpec((1, 1, H), lambda b, j, sl: (b, 0, 0)),
             pl.BlockSpec((1, block_n, d_c), lambda b, j, sl: (b, j, 0)),
             pl.BlockSpec((1, block_n, d_r), lambda b, j, sl: (b, j, 0)),
-            pl.BlockSpec((1, block_n), lambda b, j, sl: (b, j)),
+            pl.BlockSpec((1, 1, block_n), lambda b, j, sl: (b, 0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, H, d_c), lambda b, j, sl: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j, sl: (b, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H, d_c), jnp.float32),
-        ],
+        out_specs=_single_pass_out_specs(H, d_c),
+        scratch_shapes=_state_scratch(H, d_c),
     )
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, d_c), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-        ],
-        interpret=interpret,
-    )(seq_lens, q_c8, q_r, sigma_q, content, rope, sigma_k)
+        out_shape=_single_pass_out_shape(B, H, d_c),
+        interpret=resolve_interpret(interpret),
+    )(seq_lens, q_c8, q_r, unit_rows(sigma_q), content, rope,
+      unit_rows(sigma_k))
+    return o, lse[:, 0]
+
+
+def _state_scratch(rows: int, d_c: int) -> list:
+    """VMEM scratch of the online-softmax state: m, l, sigma_p ([rows]) and
+    the [rows, d_c] accumulator."""
+    return [pltpu.VMEM((rows,), jnp.float32), pltpu.VMEM((rows,), jnp.float32),
+            pltpu.VMEM((rows,), jnp.float32),
+            pltpu.VMEM((rows, d_c), jnp.float32)]
+
+
+def _single_pass_out_specs(H: int, d_c: int) -> list:
+    """(o, lse) output blocks of one batch row, for any grid led by b."""
+    return [pl.BlockSpec((1, H, d_c), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, 1, H), lambda b, *_: (b, 0, 0))]
+
+
+def _single_pass_out_shape(B: int, H: int, d_c: int) -> list:
+    return [jax.ShapeDtypeStruct((B, H, d_c), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, H), jnp.float32)]
 
 
 # ---------------------------------------------------------------------------
@@ -335,14 +353,14 @@ def _mla_decode_splitkv_kernel(
     # inputs (VMEM blocks)
     q_c_ref,                # [1, R, d_c]   R = q_len * H query rows
     q_r_ref,                # [1, R, d_r]
-    sigma_q_ref,            # [1, R]
+    sigma_q_ref,            # [1, 1, R]
     content_ref,            # [1, bn, d_c]
     rope_ref,               # [1, bn, d_r]
-    sigma_k_ref,            # [1, bn]
+    sigma_k_ref,            # [1, 1, bn]
     # outputs (per-split partials)
     o_ref,                  # [1, 1, R, d_c] f32
-    lse_ref,                # [1, 1, R]      f32 (scale-carrying LSE)
-    sp_ref_out,             # [1, 1, R]      f32 (final per-split sigma_p)
+    lse_ref,                # [1, 1, 1, R]   f32 (scale-carrying LSE)
+    sp_ref_out,             # [1, 1, 1, R]   f32 (final per-split sigma_p)
     # scratch
     m_ref, l_ref, sp_ref,   # [R]
     acc_ref,                # [R, d_c]
@@ -374,7 +392,7 @@ def _mla_decode_splitkv_kernel(
     def _compute():
         qc = q_c_ref[0].astype(jnp.float32)
         qr = q_r_ref[0].astype(jnp.float32)
-        sq = sigma_q_ref[0].astype(jnp.float32)
+        sq = sigma_q_ref[0, 0].astype(jnp.float32)
         c = content_ref[0].astype(jnp.float32)
         r = rope_ref[0].astype(jnp.float32)
         sk = sigma_k_ref[0].astype(jnp.float32)
@@ -411,19 +429,22 @@ def _mla_decode_splitkv_kernel(
             # rescaling is a pure integer exponent add. Empty splits publish
             # (0, 0, 0) and contribute nothing.
             o_ref[0, 0] = acc_ref[...]
-            lse_ref[0, 0] = l
-            sp_ref_out[0, 0] = jnp.where(has, m_ref[...] + sp_ref[...], 0.0)
+            lse_ref[0, 0, 0] = l
+            sp_ref_out[0, 0, 0] = jnp.where(has, m_ref[...] + sp_ref[...],
+                                            0.0)
         else:
             # Empty splits (no live block touched the state) publish a
             # neutral partial: o = 0, lse = NEG_INF — the combine weight
             # exp(lse - m*) then vanishes. l > 0 iff at least one valid
             # token was accumulated.
+            # (the row mask is rebuilt from l as a column: Mosaic cannot
+            # reshape a boolean vector)
             safe_l = jnp.where(has, l, 1.0)
-            o_ref[0, 0] = jnp.where(has[:, None],
+            o_ref[0, 0] = jnp.where(l[:, None] > 0.0,
                                     acc_ref[...] / safe_l[:, None], 0.0)
-            lse_ref[0, 0] = jnp.where(
+            lse_ref[0, 0, 0] = jnp.where(
                 has, m_ref[...] + jnp.log(sp_ref[...] * safe_l), NEG_INF)
-            sp_ref_out[0, 0] = sp_ref[...]
+            sp_ref_out[0, 0, 0] = sp_ref[...]
 
 
 def _clamped_block_index(seq_lens_ref, b, s_id, j, blocks_per_split, block_n):
@@ -445,7 +466,7 @@ def _splitkv_partials_call(
     num_splits: int,
     H: int,
     d_c: int,
-    interpret: bool,
+    interpret: bool | None,
     operands: tuple,
 ):
     """One shared split/combine code path for BOTH the contiguous and the paged
@@ -453,33 +474,30 @@ def _splitkv_partials_call(
     the scale-carrying LSE), identical VMEM scratch for the online-softmax
     state, identical pallas_call plumbing. Callers differ only in their grid,
     input BlockSpecs (clamped contiguous block index vs page-table lookup) and
-    scalar-prefetch operands. Returns the raw (o, lse, sigma_p) partials."""
+    scalar-prefetch operands. Returns the raw (o, lse, sigma_p) partials,
+    ``[B, S, H, d_c]`` / ``[B, S, H]`` / ``[B, S, H]``."""
+    vec = pl.BlockSpec((1, 1, 1, H), lambda b, s, j, *_: (b, s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, H, d_c), lambda b, s, j, *_: (b, s, 0, 0)),
-            pl.BlockSpec((1, 1, H), lambda b, s, j, *_: (b, s, 0)),
-            pl.BlockSpec((1, 1, H), lambda b, s, j, *_: (b, s, 0)),
+            vec, vec,
         ],
-        scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H, d_c), jnp.float32),
-        ],
+        scratch_shapes=_state_scratch(H, d_c),
     )
-    return pl.pallas_call(
+    o_p, lse_p, sp_p = pl.pallas_call(
         kernel_body,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, num_splits, H, d_c), jnp.float32),
-            jax.ShapeDtypeStruct((B, num_splits, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, num_splits, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, num_splits, 1, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, num_splits, 1, H), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*operands)
+    return o_p, lse_p[:, :, 0], sp_p[:, :, 0]
 
 
 def _flatten_q(q_c8, q_r, sigma_q):
@@ -526,7 +544,7 @@ def mla_decode_splitkv_pallas(
     num_splits: int,
     block_n: int = 128,
     fmt: str = "fp8_e4m3",
-    interpret: bool = True,
+    interpret: bool | None = None,
     return_partials: bool = False,
     rescale: str = "fma",
 ):
@@ -565,7 +583,8 @@ def mla_decode_splitkv_pallas(
         return (b, _clamped_block_index(sl, b, s, j, blocks_per_split, block_n), 0)
 
     def sk_idx(b, s, j, sl):
-        return (b, _clamped_block_index(sl, b, s, j, blocks_per_split, block_n))
+        return (b, 0, _clamped_block_index(sl, b, s, j, blocks_per_split,
+                                           block_n))
 
     o_p, lse_p, sp_p = _splitkv_partials_call(
         kernel,
@@ -573,16 +592,24 @@ def mla_decode_splitkv_pallas(
         in_specs=[
             pl.BlockSpec((1, R, d_c), lambda b, s, j, sl: (b, 0, 0)),
             pl.BlockSpec((1, R, d_r), lambda b, s, j, sl: (b, 0, 0)),
-            pl.BlockSpec((1, R), lambda b, s, j, sl: (b, 0)),
+            pl.BlockSpec((1, 1, R), lambda b, s, j, sl: (b, 0, 0)),
             pl.BlockSpec((1, block_n, d_c), kv_idx),
             pl.BlockSpec((1, block_n, d_r), kv_idx),
-            pl.BlockSpec((1, block_n), sk_idx),
+            pl.BlockSpec((1, 1, block_n), sk_idx),
         ],
         num_scalar_prefetch=1,
         B=B, num_splits=num_splits, H=R, d_c=d_c, interpret=interpret,
-        operands=(seq_lens, q_c8, q_r, sigma_q, content, rope, sigma_k),
+        operands=(seq_lens, q_c8, q_r, unit_rows(sigma_q), content, rope,
+                  unit_rows(sigma_k)),
     )
+    return _combine(q_len, H, o_p, lse_p, sp_p, rescale=rescale,
+                    interpret=interpret, return_partials=return_partials)
 
+
+def _combine(q_len, H, o_p, lse_p, sp_p, *, rescale, interpret,
+             return_partials):
+    """Merge split partials (LSE max-shift, or the AMLA exponent-add) and
+    restore the q_len axis of a verify block."""
     if rescale == "amla":
         o, lse = amla_combine_pallas(o_p, lse_p, sp_p, interpret=interpret)
     else:
@@ -608,34 +635,34 @@ def _lse_combine_kernel(o_p_ref, lse_p_ref, o_ref, lse_ref):
     den = jnp.sum(w, axis=0)                           # [H]
     num = jnp.sum(w[:, :, None] * o_p, axis=0)         # [H, d_c]
     o_ref[0] = num / den[:, None]
-    lse_ref[0] = m_star + jnp.log(den)
+    lse_ref[0, 0] = m_star + jnp.log(den)
+
+
+def _combine_call(kernel_body, o_partial, *vec_partials, interpret):
+    """pallas_call plumbing shared by both combines: one batch row per grid
+    step, ``[B, S, H, d_c]`` + ``[B, S, H]`` partials in, (o, lse) out."""
+    B, S, H, d_c = o_partial.shape
+    o, lse = pl.pallas_call(
+        kernel_body,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, S, H, d_c), lambda b: (b, 0, 0, 0))]
+        + [pl.BlockSpec((1, S, H), lambda b: (b, 0, 0))] * len(vec_partials),
+        out_specs=_single_pass_out_specs(H, d_c),
+        out_shape=_single_pass_out_shape(B, H, d_c),
+        interpret=resolve_interpret(interpret),
+    )(o_partial, *vec_partials)
+    return o, lse[:, 0]
 
 
 def lse_combine_pallas(
     o_partial: jax.Array,     # [B, S, H, d_c] f32
     lse_partial: jax.Array,   # [B, S, H] f32 (scale-carrying, NEG_INF if empty)
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Combine split-KV partials: returns (o [B,H,d_c], lse [B,H])."""
-    B, S, H, d_c = o_partial.shape
-    return pl.pallas_call(
-        _lse_combine_kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, S, H, d_c), lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec((1, S, H), lambda b: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, H, d_c), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, d_c), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-        ],
-        interpret=interpret,
-    )(o_partial, lse_partial)
+    return _combine_call(_lse_combine_kernel, o_partial, lse_partial,
+                         interpret=interpret)
 
 
 def _amla_combine_kernel(acc_p_ref, l_p_ref, g_p_ref, o_ref, lse_ref):
@@ -659,7 +686,7 @@ def _amla_combine_kernel(acc_p_ref, l_p_ref, g_p_ref, o_ref, lse_ref):
     den = jnp.sum(amla.exp2_mul(l_p, k), axis=0)                 # [H]
     num = jnp.sum(amla.exp2_mul(acc_p, k[:, :, None]), axis=0)   # [H, d_c]
     o_ref[0] = num / den[:, None]
-    lse_ref[0] = k_star * amla.LN2 + jnp.log(den)
+    lse_ref[0, 0] = k_star * amla.LN2 + jnp.log(den)
 
 
 def amla_combine_pallas(
@@ -667,28 +694,11 @@ def amla_combine_pallas(
     l_partial: jax.Array,     # [B, S, H] f32 raw l~ (0 if empty)
     g_partial: jax.Array,     # [B, S, H] f32 integer grid exponents i + e
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Combine AMLA split-KV partials: returns (o [B,H,d_c], lse [B,H])."""
-    B, S, H, d_c = acc_partial.shape
-    return pl.pallas_call(
-        _amla_combine_kernel,
-        grid=(B,),
-        in_specs=[
-            pl.BlockSpec((1, S, H, d_c), lambda b: (b, 0, 0, 0)),
-            pl.BlockSpec((1, S, H), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, S, H), lambda b: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, H, d_c), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, d_c), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-        ],
-        interpret=interpret,
-    )(acc_partial, l_partial, g_partial)
+    return _combine_call(_amla_combine_kernel, acc_partial, l_partial,
+                         g_partial, interpret=interpret)
 
 
 def mla_decode_paged_pallas(
@@ -703,14 +713,14 @@ def mla_decode_paged_pallas(
     *,
     softmax_scale: float,
     fmt: str = "fp8_e4m3",
-    interpret: bool = True,
+    interpret: bool | None = None,
     rescale: str = "fma",
 ) -> tuple[jax.Array, jax.Array]:
     """Paged-pool SnapMLA decode: the page table is scalar-prefetched and
     drives the BlockSpec index maps (TPU-native PagedAttention)."""
     B, H, d_c = q_c8.shape
     d_r = q_r.shape[-1]
-    n_pages, page, _ = content_pool.shape
+    page = content_pool.shape[1]
     P = page_table.shape[1]
     qmax = quant.qmax_for(fmt) if fmt != "none" else 1.0
 
@@ -720,52 +730,30 @@ def mla_decode_paged_pallas(
         in_specs=[
             pl.BlockSpec((1, H, d_c), lambda b, j, sl, pt: (b, 0, 0)),
             pl.BlockSpec((1, H, d_r), lambda b, j, sl, pt: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j, sl, pt: (b, 0)),
+            pl.BlockSpec((1, 1, H), lambda b, j, sl, pt: (b, 0, 0)),
             # the page table drives the DMA source: TPU-native PagedAttention
             pl.BlockSpec((1, page, d_c), lambda b, j, sl, pt: (pt[b, j], 0, 0)),
             pl.BlockSpec((1, page, d_r), lambda b, j, sl, pt: (pt[b, j], 0, 0)),
-            pl.BlockSpec((1, page), lambda b, j, sl, pt: (pt[b, j], 0)),
+            pl.BlockSpec((1, 1, page), lambda b, j, sl, pt: (pt[b, j], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, H, d_c), lambda b, j, sl, pt: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, j, sl, pt: (b, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H, d_c), jnp.float32),
-        ],
+        out_specs=_single_pass_out_specs(H, d_c),
+        scratch_shapes=_state_scratch(H, d_c),
     )
 
     def kernel_paged(sl_ref, pt_ref, *rest):
-        return _paged_body(sl_ref, pt_ref, *rest,
-                           softmax_scale=softmax_scale, page=page, fmt=fmt,
-                           qmax=qmax, rescale=rescale)
+        del pt_ref  # only used by the index maps
+        return _mla_decode_kernel(
+            sl_ref, *rest, softmax_scale=softmax_scale, block_n=page,
+            fmt=fmt, qmax=qmax, rescale=rescale)
 
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel_paged,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B, H, d_c), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-        ],
-        interpret=interpret,
-    )(seq_lens, page_table, q_c8, q_r, sigma_q, content_pool, rope_pool, scale_pool)
-
-
-def _paged_body(seq_lens_ref, page_table_ref, q_c_ref, q_r_ref, sigma_q_ref,
-                content_ref, rope_ref, sigma_k_ref, o_ref, lse_ref,
-                m_ref, l_ref, sp_ref, acc_ref, *,
-                softmax_scale, page, fmt, qmax, rescale="fma"):
-    # identical math to _mla_decode_kernel, with 3D (1, page, d) blocks
-    del page_table_ref  # only used by the index maps
-    _mla_decode_kernel(
-        seq_lens_ref, q_c_ref, q_r_ref, sigma_q_ref,
-        content_ref, rope_ref, sigma_k_ref, o_ref, lse_ref,
-        m_ref, l_ref, sp_ref, acc_ref,
-        softmax_scale=softmax_scale, block_n=page, fmt=fmt, qmax=qmax,
-        paged=False, rescale=rescale)
+        out_shape=_single_pass_out_shape(B, H, d_c),
+        interpret=resolve_interpret(interpret),
+    )(seq_lens, page_table, q_c8, q_r, unit_rows(sigma_q), content_pool,
+      rope_pool, unit_rows(scale_pool))
+    return o, lse[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -804,7 +792,7 @@ def mla_decode_paged_splitkv_pallas(
     softmax_scale: float,
     num_splits: int,
     fmt: str = "fp8_e4m3",
-    interpret: bool = True,
+    interpret: bool | None = None,
     return_partials: bool = False,
     rescale: str = "fma",
 ):
@@ -841,31 +829,21 @@ def mla_decode_paged_splitkv_pallas(
     def kv_idx(b, s, j, sl, pt):
         return (_clamped_page_id(sl, pt, b, s, j, pages_per_split, page), 0, 0)
 
-    def sk_idx(b, s, j, sl, pt):
-        return (_clamped_page_id(sl, pt, b, s, j, pages_per_split, page), 0)
-
     o_p, lse_p, sp_p = _splitkv_partials_call(
         kernel,
         grid=(B, num_splits, pages_per_split),
         in_specs=[
             pl.BlockSpec((1, R, d_c), lambda b, s, j, sl, pt: (b, 0, 0)),
             pl.BlockSpec((1, R, d_r), lambda b, s, j, sl, pt: (b, 0, 0)),
-            pl.BlockSpec((1, R), lambda b, s, j, sl, pt: (b, 0)),
+            pl.BlockSpec((1, 1, R), lambda b, s, j, sl, pt: (b, 0, 0)),
             pl.BlockSpec((1, page, d_c), kv_idx),
             pl.BlockSpec((1, page, d_r), kv_idx),
-            pl.BlockSpec((1, page), sk_idx),
+            pl.BlockSpec((1, 1, page), kv_idx),
         ],
         num_scalar_prefetch=2,      # seq_lens, page_table
         B=B, num_splits=num_splits, H=R, d_c=d_c, interpret=interpret,
-        operands=(seq_lens, page_table, q_c8, q_r, sigma_q,
-                  content_pool, rope_pool, scale_pool),
+        operands=(seq_lens, page_table, q_c8, q_r, unit_rows(sigma_q),
+                  content_pool, rope_pool, unit_rows(scale_pool)),
     )
-
-    if rescale == "amla":
-        o, lse = amla_combine_pallas(o_p, lse_p, sp_p, interpret=interpret)
-    else:
-        o, lse = lse_combine_pallas(o_p, lse_p, interpret=interpret)
-    o, lse, partials = _unflatten_rows(q_len, H, o, lse, (o_p, lse_p, sp_p))
-    if return_partials:
-        return o, lse, partials
-    return o, lse
+    return _combine(q_len, H, o_p, lse_p, sp_p, rescale=rescale,
+                    interpret=interpret, return_partials=return_partials)

@@ -8,7 +8,8 @@ oracle). ``num_splits=None`` resolves through ``resolve_num_splits`` — the
 profile-driven autotuner (``autotune.SplitProfile``, measured sweeps keyed on
 (capacity, block_n, batch), emitted by the benchmarks as a JSON artifact)
 with ``default_num_splits``'s context-length heuristic as fallback. On CPU
-the kernels run in interpret mode; on TPU set interpret=False.
+the kernels run in interpret mode and on TPU compiled (``interpret=None``
+resolves through ``runtime.platform.resolve_interpret``).
 
 Cache alignment: the cache capacity must be a multiple of ``block_n``
 (``init_mla_cache`` rounds ``max_len`` up to the page size, so this holds by
@@ -148,7 +149,7 @@ def snapmla_decode(
     fmt: str = "fp8_e4m3",
     num_splits: int | None = None,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
     rescale: str = "fma",
 ) -> tuple[jax.Array, jax.Array]:
     """Decode one token per sequence. Returns (o_latent [B,H,d_c] f32, lse).
@@ -183,7 +184,7 @@ def _snapmla_decode_impl(
     fmt: str,
     num_splits: int,
     use_kernel: bool,
-    interpret: bool,
+    interpret: bool | None,
     rescale: str = "fma",
 ) -> tuple[jax.Array, jax.Array]:
     splits = num_splits
@@ -221,7 +222,7 @@ def snapmla_decode_paged(
     fmt: str = "fp8_e4m3",
     num_splits: int | None = None,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
     rescale: str = "fma",
 ) -> tuple[jax.Array, jax.Array]:
     """Decode one token per sequence against a paged pool.
@@ -262,7 +263,7 @@ def _snapmla_decode_paged_impl(
     fmt: str,
     num_splits: int,
     use_kernel: bool,
-    interpret: bool,
+    interpret: bool | None,
     rescale: str = "fma",
 ) -> tuple[jax.Array, jax.Array]:
     splits = num_splits

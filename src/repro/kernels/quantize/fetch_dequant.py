@@ -24,12 +24,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.kvcache import MLACache, PagedMLAPool
+from repro.kernels.mla_decode.kernel import unit_rows
+from repro.runtime.platform import resolve_interpret
+
+
+def _column(row: jax.Array) -> jax.Array:
+    """[1, n] -> [n, 1], exactly: each output is one value summed with
+    zeros (a lane-to-sublane move without a transpose)."""
+    n = row.shape[-1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
 
 def _fetch_dequant_kernel(content_ref, rope_ref, scale_ref, out_ref, *, d_c):
     c = content_ref[0].astype(jnp.float32)              # [page, d_c]
     r = rope_ref[0].astype(jnp.float32)                 # [page, d_r]
-    s = scale_ref[0].astype(jnp.float32)[:, None]       # [page, 1]
+    s = _column(scale_ref[0].astype(jnp.float32))       # [page, 1]
     out_ref[0, :, :d_c] = (c * s).astype(out_ref.dtype)
     out_ref[0, :, d_c:] = (r * s).astype(out_ref.dtype)  # undo Eq.-6 prescale
 
@@ -65,7 +76,8 @@ def _bounded_paged_fetch_body(cs_ref, pt_ref, content_ref, rope_ref,
 
 
 def fetch_dequant_pallas(cache: MLACache, *, page: int = 128,
-                         out_dtype=jnp.bfloat16, interpret: bool = True):
+                         out_dtype=jnp.bfloat16,
+                         interpret: bool | None = None):
     """MLACache -> dequantized [B, N, d_c + d_r] keys (content|rope) in bf16."""
     B, N, d_c = cache.content.shape
     d_r = cache.rope.shape[-1]
@@ -77,12 +89,12 @@ def fetch_dequant_pallas(cache: MLACache, *, page: int = 128,
         in_specs=[
             pl.BlockSpec((1, page, d_c), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, page, d_r), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, page), lambda b, j: (b, j)),
+            pl.BlockSpec((1, 1, page), lambda b, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, page, d_c + d_r), lambda b, j: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((B, N, d_c + d_r), out_dtype),
-        interpret=interpret,
-    )(cache.content, cache.rope, cache.scale)
+        interpret=resolve_interpret(interpret),
+    )(cache.content, cache.rope, unit_rows(cache.scale))
 
 
 def fetch_dequant_ref(cache: MLACache, out_dtype=jnp.bfloat16):
@@ -95,7 +107,7 @@ def fetch_dequant_ref(cache: MLACache, out_dtype=jnp.bfloat16):
 def paged_fetch_dequant_pallas(pool: PagedMLAPool, *,
                                chunk_start: jax.Array | None = None,
                                out_dtype=jnp.bfloat16,
-                               interpret: bool = True):
+                               interpret: bool | None = None):
     """Paged Fused-Fetch-Dequant: the page table is scalar-prefetched and
     drives the DMA source of each (batch, logical-page) grid cell — the same
     TPU-native PagedAttention addressing the paged decode kernels use, so
@@ -116,6 +128,8 @@ def paged_fetch_dequant_pallas(pool: PagedMLAPool, *,
     d_r = pool.rope.shape[-1]
     B, P = pool.page_table.shape
     out_shape = jax.ShapeDtypeStruct((B, P * page, d_c + d_r), out_dtype)
+    interpret = resolve_interpret(interpret)
+    scales = unit_rows(pool.scale)          # [n_pages, 1, page]
     if chunk_start is None:
         kernel = functools.partial(_paged_fetch_dequant_body, d_c=d_c)
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -124,7 +138,7 @@ def paged_fetch_dequant_pallas(pool: PagedMLAPool, *,
             in_specs=[
                 pl.BlockSpec((1, page, d_c), lambda b, j, pt: (pt[b, j], 0, 0)),
                 pl.BlockSpec((1, page, d_r), lambda b, j, pt: (pt[b, j], 0, 0)),
-                pl.BlockSpec((1, page), lambda b, j, pt: (pt[b, j], 0)),
+                pl.BlockSpec((1, 1, page), lambda b, j, pt: (pt[b, j], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, page, d_c + d_r),
                                    lambda b, j, pt: (b, j, 0)),
@@ -134,7 +148,7 @@ def paged_fetch_dequant_pallas(pool: PagedMLAPool, *,
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,
-        )(pool.page_table, pool.content, pool.rope, pool.scale)
+        )(pool.page_table, pool.content, pool.rope, scales)
 
     cs = chunk_start.astype(jnp.int32)
 
@@ -154,9 +168,9 @@ def paged_fetch_dequant_pallas(pool: PagedMLAPool, *,
             pl.BlockSpec((1, page, d_r),
                          lambda b, j, cs, pt: (pt[b, _live_page(j, cs[b])],
                                                0, 0)),
-            pl.BlockSpec((1, page),
+            pl.BlockSpec((1, 1, page),
                          lambda b, j, cs, pt: (pt[b, _live_page(j, cs[b])],
-                                               0)),
+                                               0, 0)),
         ],
         out_specs=pl.BlockSpec((1, page, d_c + d_r),
                                lambda b, j, cs, pt: (b, j, 0)),
@@ -166,7 +180,7 @@ def paged_fetch_dequant_pallas(pool: PagedMLAPool, *,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(cs, pool.page_table, pool.content, pool.rope, pool.scale)
+    )(cs, pool.page_table, pool.content, pool.rope, scales)
 
 
 def paged_fetch_dequant_ref(pool: PagedMLAPool, out_dtype=jnp.bfloat16,
@@ -199,7 +213,7 @@ def paged_chunked_prefill_attention(
     *,
     softmax_scale: float,
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Attend a prompt chunk against [quantized paged prefix] + [itself].
 
@@ -257,7 +271,7 @@ def paged_verify_attention(
     *,
     softmax_scale: float,
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Speculative-verify attention: [FP8 prefix] + [drafted suffix], one
     softmax.
@@ -288,7 +302,7 @@ def chunked_prefill_attention(
     softmax_scale: float,
     page: int = 128,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Attend a prompt chunk against [quantized prefix] + [itself], causal.
 

@@ -13,7 +13,7 @@ from repro.kernels.quantize import ref as _ref
 
 @partial(jax.jit, static_argnames=("d_c", "fmt", "use_kernel", "interpret"))
 def fused_q_quant(q: jax.Array, d_c: int, *, fmt: str = "fp8_e4m3",
-                  use_kernel: bool = True, interpret: bool = True):
+                  use_kernel: bool = True, interpret: bool | None = None):
     if use_kernel:
         return _k.fused_q_quant_pallas(q, d_c, fmt=fmt, interpret=interpret)
     return _ref.fused_q_quant_ref(q, d_c, fmt=fmt)
@@ -25,7 +25,8 @@ def fused_q_quant(q: jax.Array, d_c: int, *, fmt: str = "fp8_e4m3",
 @partial(jax.jit, static_argnames=("fmt", "page", "use_kernel", "interpret"))
 def fused_k_append(cache: MLACache, c_kv: jax.Array, k_r: jax.Array, *,
                    fmt: str = "fp8_e4m3", page: int = 128,
-                   use_kernel: bool = True, interpret: bool = True) -> MLACache:
+                   use_kernel: bool = True,
+                   interpret: bool | None = None) -> MLACache:
     if use_kernel:
         content, rope, scale = _k.fused_k_append_pallas(
             cache.content, cache.rope, cache.scale, c_kv, k_r, cache.seq_lens,

@@ -1,5 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# the 512 placeholder devices are host (CPU) devices: never touch a TPU,
+# which belongs to one process at a time
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede every other import (jax locks device count on first init).
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.

@@ -48,7 +48,10 @@ def run_one(arch, shape, mesh, out_dir, timeout, cost=False):
     t0 = time.time()
     try:
         p = subprocess.run(cmd, capture_output=True, text=True, timeout=timeout,
-                           env={**os.environ, "PYTHONPATH": "src"})
+                           env={**os.environ, "PYTHONPATH": "src",
+                                # parallel children must never contend
+                                # for an attached TPU
+                                "JAX_PLATFORMS": "cpu"})
         if p.returncode != 0:
             out.write_text(json.dumps({
                 "arch": arch, "shape": shape, "mesh": mesh, "status": "error",

@@ -652,8 +652,7 @@ def _chunked_prefill_mla_layer(p, cfg: ModelConfig, x, pool, chunk_start,
     q_lat = mla_lib.absorb_q(p["mixer"], q_c)          # [B, C, H, d_c]
     o_lat = FD.paged_chunked_prefill_attention(
         q_lat, q_r, pool, c_kv, k_r, chunk_start, valid,
-        softmax_scale=mcfg.softmax_scale, use_kernel=cfg.use_kernels,
-        interpret=jax.default_backend() != "tpu")
+        softmax_scale=mcfg.softmax_scale, use_kernel=cfg.use_kernels)
     x = x + mla_lib.output_proj(p["mixer"], o_lat.astype(x.dtype))
     x, _ = _apply_mlp(p, cfg, x)
     return x, pool

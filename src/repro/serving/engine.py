@@ -320,6 +320,17 @@ class ServingEngine:
             jnp.zeros((ecfg.max_batch,), jnp.int32))
         jax.block_until_ready(warm)
         self.state = warm
+        if self._verify_fn is not None:
+            # compile the verify step here too, so a compile error raises
+            # at start-up instead of reaching _dispatch_verify's fallback
+            idle = np.zeros((ecfg.max_batch,), np.int32)
+            _, warm = self._verify_fn(
+                self.params,
+                jnp.zeros((ecfg.max_batch, ecfg.spec_draft_len + 1),
+                          jnp.int32),
+                self._state_with_tables(self.table, idle), jnp.asarray(idle))
+            jax.block_until_ready(warm)
+            self.state = warm
 
         self.step_idx = 0
         self.prefill_tokens_series: list[int] = []  # prefill work per step
@@ -334,13 +345,12 @@ class ServingEngine:
         self.registry.register_collector(self._collect_occupancy)
 
         # analytic roofline annotation: per-step model bytes/FLOPs for the
-        # resolved decode backend (ref paged gather models full-span traffic;
-        # kernels stream only visited tokens)
-        try:
-            self._backend = BK.resolve_backend(
-                cfg.decode_backend, paged=True, use_kernels=cfg.use_kernels)
-        except ValueError:
-            self._backend = BK.get_backend("jnp_paged_ref")
+        # decode backend the decode step resolves (ref paged gather models
+        # full-span traffic; kernels stream only visited tokens)
+        self._backend = BK.resolve_backend(
+            self.cfg.decode_backend, paged=True, batch=ecfg.max_batch,
+            n_heads=cfg.n_heads, use_kernels=cfg.use_kernels)
+        self._device_kind = jax.devices()[0].device_kind
 
         # fault tolerance: injection plan, preemption flag, survival metrics
         self.fault_plan = fault_plan
@@ -1236,7 +1246,8 @@ class ServingEngine:
                                for r in active for t in range(K)),
             tokens_full=len(active) * K * self.span_pages * self.page,
             heads=self.cfg.n_heads, d_c=self.cfg.mla.d_c,
-            d_r=self.cfg.mla.d_rope, fmt=self.cfg.kv_fmt)
+            d_r=self.cfg.mla.d_rope, fmt=self.cfg.kv_fmt,
+            device_kind=self._device_kind)
         self._c_roof_bytes.inc(cost["bytes"])
         self._c_roof_bytes_min.inc(cost["bytes_min"])
         self._c_roof_flops.inc(cost["flops"])
@@ -1370,7 +1381,8 @@ class ServingEngine:
                 tokens_visited=sum(r.seq_len for r in active),
                 tokens_full=len(active) * self.span_pages * self.page,
                 heads=self.cfg.n_heads, d_c=self.cfg.mla.d_c,
-                d_r=self.cfg.mla.d_rope, fmt=self.cfg.kv_fmt)
+                d_r=self.cfg.mla.d_rope, fmt=self.cfg.kv_fmt,
+                device_kind=self._device_kind)
             self._c_roof_bytes.inc(cost["bytes"])
             self._c_roof_bytes_min.inc(cost["bytes_min"])
             self._c_roof_flops.inc(cost["flops"])
