@@ -410,7 +410,6 @@ _TELEMETRY_GATED = (
     "snapmla_fetch_pages_full_total",
     "snapmla_engine_prefill_skipped_tokens_total",
     "snapmla_engine_decode_tokens_total",
-    "snapmla_roofline_model_bytes_total",
 )
 
 

@@ -133,11 +133,11 @@ def main(argv: list[str] | None = None) -> int:
     m = engine.metrics()
     print(f"engine: {len(results)} requests in {time.time() - t0:.1f} s "
           f"wall (weight init and compiles included), backend "
-          f"{m['roofline']['backend']}, interpret={resolve_interpret()}")
+          f"{m['decode_backend']}, interpret={resolve_interpret()}")
     print(f"engine decode: {m['wall']['decode_tok_per_s']:.1f} tok/s "
           f"(smoke figure from the engine's wall clock, not a benchmark)")
-    if m["roofline"]["backend"] != "pallas_paged_splitkv":
-        return _fail(f"decode backend {m['roofline']['backend']}")
+    if m["decode_backend"] != "pallas_paged_splitkv":
+        return _fail(f"decode backend {m['decode_backend']}")
     if resolve_interpret():
         return _fail("Pallas kernels resolved to interpret mode")
     not_done = [(r.rid, r.status) for r in results if r.status != "done"]

@@ -14,7 +14,7 @@ does), then prints three tables derived purely from the trace:
   * page occupancy — min/mean/peak of the per-step pool counter samples;
   * speculative decoding (``serve --spec-draft``) — verify steps, drafted
     vs accepted totals, and the acceptance rate, from the verify-flagged
-    decode phase spans.
+    decode (drafted) and sample (accepted) phase spans.
 
 Exit code is non-zero on validation failure, so CI can gate on it.
 """
@@ -73,8 +73,10 @@ def summarize(payload: dict) -> dict:
                     and e.get("args", {}).get("verify"):
                 spec["verify_steps"] += 1
                 spec["drafted"] += e["args"].get("drafted", 0)
-                spec["accepted"] += e["args"].get("accepted", 0)
                 spec["rows"] += e["args"].get("rows", 0)
+            elif ph == "X" and e.get("name") == "sample" \
+                    and e.get("args", {}).get("verify"):
+                spec["accepted"] += e["args"].get("accepted", 0)
             continue
         rid = e.get("tid")
         r = reqs.setdefault(rid, {"rid": rid, "queued": None, "admit": None,

@@ -152,4 +152,5 @@ def gqa_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
         interpret=resolve_interpret(interpret),
+        name="gqa_decode",
     )(positions, q, k8, v8, k_scale, v_scale, slot_pos)

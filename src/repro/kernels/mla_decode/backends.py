@@ -261,70 +261,6 @@ register(DecodeBackend("shard_map", "contiguous", "shard_map",
 
 
 # ---------------------------------------------------------------------------
-# analytic dispatch cost (telemetry annotation; see obs/)
-# ---------------------------------------------------------------------------
-
-# Published per-chip peaks, keyed by ``jax.Device.device_kind``: (HBM
-# bytes/s, bf16 FLOP/s). TPU v5e: 819 GB/s HBM, 197 TFLOP/s bf16 (Google
-# Cloud TPU documentation, "TPU v5e"). A device not listed here gets no
-# modeled time.
-PEAKS: dict[str, tuple[float, float]] = {
-    "TPU v5 lite": (819e9, 197e12),
-}
-
-
-def token_cost(fmt: str, d_c: int, d_r: int, heads: int
-               ) -> tuple[int, int]:
-    """(bytes streamed, FLOPs computed) per CACHED TOKEN of one decode
-    dispatch: quantized content byte/elem + bf16 rope + f32 per-token
-    scale, QK + PV per head — the Eq. 12–13 pipeline's traffic model."""
-    if fmt == "none":
-        bytes_tok = (d_c + d_r) * 2
-    else:
-        bytes_tok = d_c * 1 + d_r * 2 + 4
-    flops_tok = (2 * (d_c + d_r) + 2 * d_c) * heads
-    return bytes_tok, flops_tok
-
-
-def dispatch_cost(backend: "DecodeBackend | str", *, tokens_visited: int,
-                  tokens_full: int, heads: int, d_c: int, d_r: int,
-                  fmt: str, device_kind: str | None = None) -> dict:
-    """Analytic bytes/FLOPs annotation for ONE decode dispatch.
-
-    ``tokens_visited`` is the KV-token work the split-KV early exit
-    actually touches (``sum(seq_lens)``, which the engine's blocks-visited
-    counters already track); ``tokens_full`` is the dense full-span sweep.
-    Kernel backends stream only the visited tokens; the paged REF backend
-    materializes the whole page-table span (``paged_gather``), so its
-    modeled traffic is the full sweep — the annotation makes that
-    structural difference visible per step. ``achieved_fraction`` is
-    roofline-minimum bytes over modeled bytes: 1.0 = the dispatch streams
-    exactly the live context, lower = dead traffic. ``t_model_us`` is the
-    roofline time on ``device_kind``'s published peaks (``PEAKS``), None
-    for a device without an entry."""
-    b = get_backend(backend) if isinstance(backend, str) else backend
-    bytes_tok, flops_tok = token_cost(fmt, d_c, d_r, heads)
-    streamed = tokens_full if (b.kind == "ref" and b.layout == "paged") \
-        else tokens_visited
-    streamed = max(streamed, tokens_visited)
-    model_bytes = streamed * bytes_tok
-    min_bytes = tokens_visited * bytes_tok
-    flops = tokens_visited * flops_tok
-    peaks = PEAKS.get(device_kind or "")
-    t_model_us = (max(model_bytes / peaks[0], flops / peaks[1]) * 1e6
-                  if peaks else None)
-    return {
-        "backend": b.name,
-        "bytes": model_bytes,
-        "bytes_min": min_bytes,
-        "flops": flops,
-        "achieved_fraction": (min_bytes / model_bytes
-                              if model_bytes else 1.0),
-        "t_model_us": t_model_us,
-    }
-
-
-# ---------------------------------------------------------------------------
 # resolution — the ONE decode-dispatch decision point
 # ---------------------------------------------------------------------------
 

@@ -319,6 +319,7 @@ def mla_decode_pallas(
         grid_spec=grid_spec,
         out_shape=_single_pass_out_shape(B, H, d_c),
         interpret=resolve_interpret(interpret),
+        name="mla_decode",
     )(seq_lens, q_c8, q_r, unit_rows(sigma_q), content, rope,
       unit_rows(sigma_k))
     return o, lse[:, 0]
@@ -459,6 +460,7 @@ def _clamped_block_index(seq_lens_ref, b, s_id, j, blocks_per_split, block_n):
 def _splitkv_partials_call(
     kernel_body,
     *,
+    name: str,
     grid: tuple,
     in_specs: list,
     num_scalar_prefetch: int,
@@ -473,8 +475,9 @@ def _splitkv_partials_call(
     split-KV kernels: identical per-split partial layout ([B, S, H, ...] with
     the scale-carrying LSE), identical VMEM scratch for the online-softmax
     state, identical pallas_call plumbing. Callers differ only in their grid,
-    input BlockSpecs (clamped contiguous block index vs page-table lookup) and
-    scalar-prefetch operands. Returns the raw (o, lse, sigma_p) partials,
+    input BlockSpecs (clamped contiguous block index vs page-table lookup),
+    scalar-prefetch operands and kernel ``name``. Returns the raw (o, lse,
+    sigma_p) partials,
     ``[B, S, H, d_c]`` / ``[B, S, H]`` / ``[B, S, H]``."""
     vec = pl.BlockSpec((1, 1, 1, H), lambda b, s, j, *_: (b, s, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -496,6 +499,7 @@ def _splitkv_partials_call(
             jax.ShapeDtypeStruct((B, num_splits, 1, H), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
+        name=name,
     )(*operands)
     return o_p, lse_p[:, :, 0], sp_p[:, :, 0]
 
@@ -588,6 +592,7 @@ def mla_decode_splitkv_pallas(
 
     o_p, lse_p, sp_p = _splitkv_partials_call(
         kernel,
+        name="mla_decode_splitkv",
         grid=(B, num_splits, blocks_per_split),
         in_specs=[
             pl.BlockSpec((1, R, d_c), lambda b, s, j, sl: (b, 0, 0)),
@@ -638,7 +643,7 @@ def _lse_combine_kernel(o_p_ref, lse_p_ref, o_ref, lse_ref):
     lse_ref[0, 0] = m_star + jnp.log(den)
 
 
-def _combine_call(kernel_body, o_partial, *vec_partials, interpret):
+def _combine_call(kernel_body, name, o_partial, *vec_partials, interpret):
     """pallas_call plumbing shared by both combines: one batch row per grid
     step, ``[B, S, H, d_c]`` + ``[B, S, H]`` partials in, (o, lse) out."""
     B, S, H, d_c = o_partial.shape
@@ -650,6 +655,7 @@ def _combine_call(kernel_body, o_partial, *vec_partials, interpret):
         out_specs=_single_pass_out_specs(H, d_c),
         out_shape=_single_pass_out_shape(B, H, d_c),
         interpret=resolve_interpret(interpret),
+        name=name,
     )(o_partial, *vec_partials)
     return o, lse[:, 0]
 
@@ -661,8 +667,8 @@ def lse_combine_pallas(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Combine split-KV partials: returns (o [B,H,d_c], lse [B,H])."""
-    return _combine_call(_lse_combine_kernel, o_partial, lse_partial,
-                         interpret=interpret)
+    return _combine_call(_lse_combine_kernel, "lse_combine", o_partial,
+                         lse_partial, interpret=interpret)
 
 
 def _amla_combine_kernel(acc_p_ref, l_p_ref, g_p_ref, o_ref, lse_ref):
@@ -697,8 +703,8 @@ def amla_combine_pallas(
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Combine AMLA split-KV partials: returns (o [B,H,d_c], lse [B,H])."""
-    return _combine_call(_amla_combine_kernel, acc_partial, l_partial,
-                         g_partial, interpret=interpret)
+    return _combine_call(_amla_combine_kernel, "amla_combine", acc_partial,
+                         l_partial, g_partial, interpret=interpret)
 
 
 def mla_decode_paged_pallas(
@@ -751,6 +757,7 @@ def mla_decode_paged_pallas(
         grid_spec=grid_spec,
         out_shape=_single_pass_out_shape(B, H, d_c),
         interpret=resolve_interpret(interpret),
+        name="mla_decode_paged",
     )(seq_lens, page_table, q_c8, q_r, unit_rows(sigma_q), content_pool,
       rope_pool, unit_rows(scale_pool))
     return o, lse[:, 0]
@@ -831,6 +838,7 @@ def mla_decode_paged_splitkv_pallas(
 
     o_p, lse_p, sp_p = _splitkv_partials_call(
         kernel,
+        name="mla_decode_paged_splitkv",
         grid=(B, num_splits, pages_per_split),
         in_specs=[
             pl.BlockSpec((1, R, d_c), lambda b, s, j, sl, pt: (b, 0, 0)),

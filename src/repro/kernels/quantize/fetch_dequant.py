@@ -94,6 +94,7 @@ def fetch_dequant_pallas(cache: MLACache, *, page: int = 128,
         out_specs=pl.BlockSpec((1, page, d_c + d_r), lambda b, j: (b, j, 0)),
         out_shape=jax.ShapeDtypeStruct((B, N, d_c + d_r), out_dtype),
         interpret=resolve_interpret(interpret),
+        name="fetch_dequant",
     )(cache.content, cache.rope, unit_rows(cache.scale))
 
 
@@ -148,6 +149,7 @@ def paged_fetch_dequant_pallas(pool: PagedMLAPool, *,
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=interpret,
+            name="fetch_dequant",
         )(pool.page_table, pool.content, pool.rope, scales)
 
     cs = chunk_start.astype(jnp.int32)
@@ -180,6 +182,7 @@ def paged_fetch_dequant_pallas(pool: PagedMLAPool, *,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="fetch_dequant",
     )(cs, pool.page_table, pool.content, pool.rope, scales)
 
 

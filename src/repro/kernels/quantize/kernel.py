@@ -74,6 +74,7 @@ def fused_q_quant_pallas(
             jax.ShapeDtypeStruct((B, 1, H), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
+        name="quantize_query",
     )(q)
     return q_c, q_r, sq[:, 0]
 
@@ -162,6 +163,7 @@ def fused_k_append_pallas(
         # rows inside pass through via the jnp.where above
         input_output_aliases={3: 0, 4: 1, 5: 2},
         interpret=resolve_interpret(interpret),
+        name="quantize_append",
     )(seq_lens, unit_rows(c_kv), unit_rows(k_r), content, rope,
       unit_rows(scale))
     return content, rope, scale[:, 0]

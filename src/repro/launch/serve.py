@@ -321,7 +321,7 @@ def run_engine(cfg, params, args):
         pages_capacity=m["pages"]["capacity"],
         saved_by_sharing=m["pages"]["saved_by_sharing"],
         evictions=m["evictions"], requeues=m["requeues"],
-        roofline=m["roofline"])
+        decode_backend=m["decode_backend"])
     f = m["faults"]
     if plan or args.restartable or f["rejected"] or f["deadline_cancelled"]:
         log("engine_faults",
@@ -672,8 +672,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="trace timestamp source: 'virtual' stamps "
                          "step*1000+offset ticks (byte-identical across "
                          "same-seed runs; ts//1000 recovers the engine "
-                         "step), 'wall' stamps real microseconds (readable, "
-                         "not reproducible)")
+                         "step), 'wall' stamps each event's real begin and "
+                         "duration in microseconds of the profiler's epoch "
+                         "clock (readable, not reproducible)")
     ap.add_argument("--log-json", action="store_true",
                     help="engine-only: emit every [serve] status line as a "
                          "single-line JSON event object instead of prose")

@@ -58,9 +58,11 @@ def init_moe_params(key, d: int, cfg: MoEConfig, dtype=jnp.float32) -> MoEParams
     )
 
 
+@jax.named_scope("moe")
 def moe_layer(params: MoEParams, cfg: MoEConfig, x: jax.Array,
               act=jax.nn.silu) -> tuple[jax.Array, jax.Array]:
-    """x [..., T, d] -> (out [..., T, d], dropped_fraction scalar)."""
+    """x [..., T, d] -> (out [..., T, d], dropped_fraction scalar); its ops
+    carry the ``moe`` named scope."""
     orig_shape = x.shape
     d = x.shape[-1]
     xt = x.reshape(-1, d)                                   # [T, d]

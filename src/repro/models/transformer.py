@@ -150,6 +150,8 @@ def init_model(key, cfg: ModelConfig, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 def _apply_mlp(p, cfg: ModelConfig, x):
+    """The layer's feed-forward half: a routed MoE (its ops under the
+    ``moe`` named scope) or a dense MLP (``mlp``)."""
     if "mlp" not in p:
         return x, 0.0
     h = L.rms_norm(x, p["ln2"])
@@ -158,7 +160,18 @@ def _apply_mlp(p, cfg: ModelConfig, x):
                                          act={"silu": jax.nn.silu,
                                               "gelu": jax.nn.gelu}[cfg.act])
         return x + out, dropped
-    return x + L.mlp(p["mlp"], h, cfg.act), 0.0
+    with jax.named_scope("mlp"):
+        return x + L.mlp(p["mlp"], h, cfg.act), 0.0
+
+
+def _head(params, x):
+    """Final norm and f32 unembedding of the rows ``x`` [..., d] ->
+    logits [..., V], under the ``head`` named scope."""
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["ln_f"])
+        table = params.get("unembed", params["embed"])
+        return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
+                          table.astype(jnp.float32))
 
 
 def _apply_block_train(p, cfg: ModelConfig, kind: str, x, positions, aux):
@@ -334,6 +347,7 @@ def _wsc(x, *spec):
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, PartitionSpec(*parts)))
 
+@jax.named_scope("attn")
 def _attn_decode(p, cfg: ModelConfig, kind: str, x_t, cache: GQACache, pos,
                  active=None):
     """One-token GQA/SWA decode against a quantized cache. ``active`` [B]
@@ -357,6 +371,7 @@ def _attn_decode(p, cfg: ModelConfig, kind: str, x_t, cache: GQACache, pos,
     return o, cache
 
 
+@jax.named_scope("attn")
 def _cross_decode(p, cfg: ModelConfig, x_t, cache: GQACache):
     """One-token cross-attention against the static (quantized) aux cache."""
     q = jnp.einsum("bd,dhk->bhk", x_t, p.wq)
@@ -371,6 +386,7 @@ def _cross_decode(p, cfg: ModelConfig, x_t, cache: GQACache):
     return jnp.einsum("bhk,hkd->bd", o.astype(x_t.dtype), p.wo)
 
 
+@jax.named_scope("attn")
 def _mla_decode(p, cfg: ModelConfig, x_t, cache, pos, active=None):
     """SnapMLA decode: Fused-Q-Quant + Fused-K-Append + backend attention.
 
@@ -523,11 +539,7 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, state,
         tail_states.append(s)
     new_state["tail"] = tail_states
 
-    x_t = L.rms_norm(x_t, params["ln_f"])
-    table = params.get("unembed", params["embed"])
-    logits = jnp.einsum("bd,vd->bv", x_t.astype(jnp.float32),
-                        table.astype(jnp.float32))
-    return logits, new_state
+    return _head(params, x_t), new_state
 
 
 # ---------------------------------------------------------------------------
@@ -623,11 +635,7 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array, state,
         tail_states.append(s)
     new_state["tail"] = tail_states
 
-    x_last = L.rms_norm(x[:, -1], params["ln_f"])
-    table = params.get("unembed", params["embed"])
-    logits = jnp.einsum("bd,vd->bv", x_last.astype(jnp.float32),
-                        table.astype(jnp.float32))
-    return logits, new_state
+    return _head(params, x[:, -1]), new_state
 
 
 # ---------------------------------------------------------------------------
@@ -640,20 +648,21 @@ def _chunked_prefill_mla_layer(p, cfg: ModelConfig, x, pool, chunk_start,
     in the FP8 pool pages at ``chunk_start + t``, then attend the chunk's
     queries against [quantized prefix pages] + [the chunk itself] (causal)
     through the fused fetch-dequant path."""
-    from repro.kernels.quantize import fetch_dequant as FD
-    mcfg = _mla_cfg(cfg)
-    ccfg = _cache_cfg(cfg, "mla")
-    C = x.shape[1]
-    positions = chunk_start[:, None] + jnp.arange(C)[None, :]
-    h = L.rms_norm(x, p["ln1"])
-    c_kv, k_r = mla_lib.project_kv(p["mixer"], mcfg, h, positions)
-    pool = paged_mla_prefill_at(pool, ccfg, c_kv, k_r, chunk_start, valid)
-    q_c, q_r = mla_lib.project_q(p["mixer"], mcfg, h, positions)
-    q_lat = mla_lib.absorb_q(p["mixer"], q_c)          # [B, C, H, d_c]
-    o_lat = FD.paged_chunked_prefill_attention(
-        q_lat, q_r, pool, c_kv, k_r, chunk_start, valid,
-        softmax_scale=mcfg.softmax_scale, use_kernel=cfg.use_kernels)
-    x = x + mla_lib.output_proj(p["mixer"], o_lat.astype(x.dtype))
+    with jax.named_scope("attn"):
+        from repro.kernels.quantize import fetch_dequant as FD
+        mcfg = _mla_cfg(cfg)
+        ccfg = _cache_cfg(cfg, "mla")
+        C = x.shape[1]
+        positions = chunk_start[:, None] + jnp.arange(C)[None, :]
+        h = L.rms_norm(x, p["ln1"])
+        c_kv, k_r = mla_lib.project_kv(p["mixer"], mcfg, h, positions)
+        pool = paged_mla_prefill_at(pool, ccfg, c_kv, k_r, chunk_start, valid)
+        q_c, q_r = mla_lib.project_q(p["mixer"], mcfg, h, positions)
+        q_lat = mla_lib.absorb_q(p["mixer"], q_c)          # [B, C, H, d_c]
+        o_lat = FD.paged_chunked_prefill_attention(
+            q_lat, q_r, pool, c_kv, k_r, chunk_start, valid,
+            softmax_scale=mcfg.softmax_scale, use_kernel=cfg.use_kernels)
+        x = x + mla_lib.output_proj(p["mixer"], o_lat.astype(x.dtype))
     x, _ = _apply_mlp(p, cfg, x)
     return x, pool
 
@@ -717,11 +726,7 @@ def chunked_prefill(params, cfg: ModelConfig, tokens: jax.Array, state,
     x_last = jnp.take_along_axis(
         x, last_idx[:, None, None].astype(jnp.int32),
         axis=1)[:, 0]                                             # [B, d]
-    x_last = L.rms_norm(x_last, params["ln_f"])
-    table = params.get("unembed", params["embed"])
-    logits = jnp.einsum("bd,vd->bv", x_last.astype(jnp.float32),
-                        table.astype(jnp.float32))
-    return logits, new_state
+    return _head(params, x_last), new_state
 
 
 # ---------------------------------------------------------------------------
@@ -736,35 +741,36 @@ def _verify_mla_layer(p, cfg: ModelConfig, x, pool, start):
     K queries against [FP8 prefix pages + the block itself] through the
     q_len>1 split-KV decode backend (causal across the block via the kernel's
     per-row limits)."""
-    mcfg = _mla_cfg(cfg)
-    ccfg = _cache_cfg(cfg, "mla")
-    B, K = x.shape[:2]
-    positions = start[:, None] + jnp.arange(K)[None, :]
-    h = L.rms_norm(x, p["ln1"])
-    c_kv, k_r = mla_lib.project_kv(p["mixer"], mcfg, h, positions)
-    # valid=ones: pool seq_lens become start + K, so every verify row's
-    # kernel limit is >= 1 (idle slots attend their own first row — finite
-    # garbage, discarded by the engine's acceptance rule). Entries past the
-    # slot's allocated pages clip to the scratch page inside prefill_at.
-    valid = jnp.ones((B, K), bool)
-    pool = paged_mla_prefill_at(pool, ccfg, c_kv, k_r, start, valid)
-    q_c, q_r = mla_lib.project_q(p["mixer"], mcfg, h, positions)
-    q_lat = mla_lib.absorb_q(p["mixer"], q_c)           # [B, K, H, d_c]
-    fmt = ccfg.fmt if ccfg.quantized else "none"
-    H = q_lat.shape[2]
-    q8, qr_s, sq = mla_kref.prepare_q(
-        q_lat.reshape(B, K * H, -1), q_r.reshape(B, K * H, -1), fmt)
-    query = BK.DecodeQuery(q8.reshape(B, K, H, -1),
-                           qr_s.reshape(B, K, H, -1),
-                           sq.reshape(B, K, H))
-    backend = BK.resolve_backend(
-        cfg.decode_backend, paged=True, batch=B, n_heads=cfg.n_heads,
-        use_kernels=cfg.use_kernels, q_len=K)
-    bcfg = BK.BackendConfig(softmax_scale=mcfg.softmax_scale,
-                            block_n=cfg.kv_block_n or ccfg.page_size, fmt=fmt,
-                            num_splits=cfg.kv_splits, rescale=cfg.kv_rescale)
-    o_lat = backend.decode(query, pool, bcfg, None)     # [B, K, H, d_c]
-    x = x + mla_lib.output_proj(p["mixer"], o_lat.astype(x.dtype))
+    with jax.named_scope("attn"):
+        mcfg = _mla_cfg(cfg)
+        ccfg = _cache_cfg(cfg, "mla")
+        B, K = x.shape[:2]
+        positions = start[:, None] + jnp.arange(K)[None, :]
+        h = L.rms_norm(x, p["ln1"])
+        c_kv, k_r = mla_lib.project_kv(p["mixer"], mcfg, h, positions)
+        # valid=ones: pool seq_lens become start + K, so every verify row's
+        # kernel limit is >= 1 (idle slots attend their own first row — finite
+        # garbage, discarded by the engine's acceptance rule). Entries past the
+        # slot's allocated pages clip to the scratch page inside prefill_at.
+        valid = jnp.ones((B, K), bool)
+        pool = paged_mla_prefill_at(pool, ccfg, c_kv, k_r, start, valid)
+        q_c, q_r = mla_lib.project_q(p["mixer"], mcfg, h, positions)
+        q_lat = mla_lib.absorb_q(p["mixer"], q_c)           # [B, K, H, d_c]
+        fmt = ccfg.fmt if ccfg.quantized else "none"
+        H = q_lat.shape[2]
+        q8, qr_s, sq = mla_kref.prepare_q(
+            q_lat.reshape(B, K * H, -1), q_r.reshape(B, K * H, -1), fmt)
+        query = BK.DecodeQuery(q8.reshape(B, K, H, -1),
+                               qr_s.reshape(B, K, H, -1),
+                               sq.reshape(B, K, H))
+        backend = BK.resolve_backend(
+            cfg.decode_backend, paged=True, batch=B, n_heads=cfg.n_heads,
+            use_kernels=cfg.use_kernels, q_len=K)
+        bcfg = BK.BackendConfig(softmax_scale=mcfg.softmax_scale,
+                                block_n=cfg.kv_block_n or ccfg.page_size, fmt=fmt,
+                                num_splits=cfg.kv_splits, rescale=cfg.kv_rescale)
+        o_lat = backend.decode(query, pool, bcfg, None)     # [B, K, H, d_c]
+        x = x + mla_lib.output_proj(p["mixer"], o_lat.astype(x.dtype))
     x, _ = _apply_mlp(p, cfg, x)
     return x, pool
 
@@ -820,8 +826,4 @@ def verify_step(params, cfg: ModelConfig, tokens: jax.Array, state,
         tail_states.append(s)
     new_state["tail"] = tail_states
 
-    x = L.rms_norm(x, params["ln_f"])
-    table = params.get("unembed", params["embed"])
-    logits = jnp.einsum("bkd,vd->bkv", x.astype(jnp.float32),
-                        table.astype(jnp.float32))
-    return logits, new_state
+    return _head(params, x), new_state

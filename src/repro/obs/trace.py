@@ -6,21 +6,29 @@ The engine emits three kinds of events into a :class:`SpanTracer`:
     ``QUEUED -> PREFILL -> DECODE`` duration spans, ``PREFILL(chunk i)``
     sub-spans for chunked admission, and ``FIRST_TOKEN`` / ``DONE`` /
     ``FAILED(reason)`` / ``REJECTED`` / ``EVICTED`` instants;
-  * **engine step-phase spans** — admit / tier_drain / prefill / decode /
-    postprocess / retire windows on the engine track, one per step in which
-    the phase did work;
+  * **engine step-phase spans** — fed by :func:`engine_span`, the one
+    helper the engine opens around each phase of a step (``step``, admit /
+    tier_drain / prefill / chunk / grow / decode / sample / sync / retire /
+    quant_probe). The same helper always opens a ``jax.profiler``
+    annotation ``engine.<phase>`` carrying the phase's counts, so a
+    profiler trace of the run holds the engine's spans beside the device's
+    ops;
   * **counter samples** — per-step pool occupancy (Chrome ``C`` events, so
     Perfetto draws the page-utilization area chart directly).
 
 CLOCKS.  The default clock is **virtual**: one engine step is
 ``TICKS_PER_STEP`` (1000) microsecond-ticks, and each step phase owns a
-fixed sub-window (``PHASE_WINDOWS``). Timestamps are therefore pure
-functions of the engine's step counter — a seeded run exports a
-byte-identical trace on any machine, and integer-dividing any request
-event's ts by ``TICKS_PER_STEP`` recovers the exact engine step, so the
-trace REPRODUCES the engine's reported TTFT / latency (in steps) rather
-than approximating them. ``clock="wall"`` stamps real microseconds instead
-(readable, not reproducible; never used by CI).
+fixed sub-window (``PHASE_WINDOWS``; phases without one are not recorded).
+Timestamps are therefore pure functions of the engine's step counter — a
+seeded run exports a byte-identical trace on any machine, and
+integer-dividing any request event's ts by ``TICKS_PER_STEP`` recovers the
+exact engine step, so the trace REPRODUCES the engine's reported TTFT /
+latency (in steps) rather than approximating them. ``clock="wall"`` stamps
+every event with its real begin and duration in microseconds of the
+profiler's clock (``time.time_ns()``, the epoch clock a ``jax.profiler``
+trace starts from: its ``profile_start_time``), so the spans line up with
+a profiler trace of the same run (readable, not reproducible; never used by
+CI).
 
 The exporter (:meth:`SpanTracer.chrome_payload`) emits the Chrome
 trace-event JSON format (``traceEvents`` array of ``X``/``i``/``C``/``M``
@@ -35,9 +43,12 @@ and the resumed steps append exactly where the snapshot stopped.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import time
 from typing import Any
+
+import jax
 
 TICKS_PER_STEP = 1000
 # fixed per-step sub-windows (virtual clock): [begin, end) tick offsets
@@ -49,6 +60,8 @@ PHASE_WINDOWS: dict[str, tuple[int, int]] = {
     "postprocess": (750, 850),
     "retire": (850, 1000),
 }
+# the virtual window of a phase whose window carries an older name
+_WINDOW_OF = {"sample": "postprocess"}
 # point offsets for request lifecycle edges (all < TICKS_PER_STEP, so
 # ts // TICKS_PER_STEP is always the emitting step)
 OFF_ADMIT = 50            # QUEUED -> PREFILL transition
@@ -75,7 +88,6 @@ class SpanTracer:
         if clock not in ("virtual", "wall"):
             raise ValueError(f"clock must be 'virtual' or 'wall': {clock!r}")
         self.clock = clock
-        self._t0 = time.time()
         self._next_sid = 1
         self._events: list[dict] = []
         # rid -> open lifecycle span {sid, name, ts, args}
@@ -90,11 +102,23 @@ class SpanTracer:
     # ------------------------------------------------------------------
 
     def ts(self, step: int, offset: int = 0) -> int:
-        """Virtual: ``step * TICKS_PER_STEP + offset`` ticks. Wall: real
-        microseconds since tracer creation (offset ignored)."""
+        """Virtual: ``step * TICKS_PER_STEP + offset`` ticks. Wall: now, in
+        microseconds of the profiler's clock (offset ignored)."""
         if self.clock == "virtual":
             return step * TICKS_PER_STEP + offset
-        return int((time.time() - self._t0) * 1e6)
+        return time.time_ns() // 1000
+
+    def _wall_span(self, t0_ns: int | None,
+                   t1_ns: int | None) -> tuple[int, int]:
+        """(ts, dur) in whole microseconds covering [t0_ns, t1_ns] (default:
+        now): the begin rounds down and the end up, so a span nested in
+        another stays inside it and a span that took any time lasts at least
+        1 us."""
+        now = time.time_ns()
+        t0_ns = now if t0_ns is None else t0_ns
+        t1_ns = now if t1_ns is None else t1_ns
+        ts = t0_ns // 1000
+        return ts, -(-t1_ns // 1000) - ts
 
     def _sid(self) -> int:
         sid = self._next_sid
@@ -106,13 +130,20 @@ class SpanTracer:
     # ------------------------------------------------------------------
 
     def step_phase(self, step: int, phase: str,
-                   args: dict[str, Any] | None = None) -> None:
-        """One step-phase window as a complete span on the engine track."""
-        begin, end = PHASE_WINDOWS[phase]
+                   args: dict[str, Any] | None = None,
+                   t0_ns: int | None = None,
+                   t1_ns: int | None = None) -> None:
+        """One step phase as a complete span on the engine track: its fixed
+        window on the virtual clock (nothing for a phase without one), its
+        real begin ``t0_ns`` and end ``t1_ns`` (default: now) on the wall
+        clock."""
         if self.clock == "virtual":
-            ts, dur = self.ts(step, begin), end - begin
+            window = PHASE_WINDOWS.get(_WINDOW_OF.get(phase, phase))
+            if window is None:
+                return
+            ts, dur = self.ts(step, window[0]), window[1] - window[0]
         else:
-            ts, dur = self.ts(step), 0
+            ts, dur = self._wall_span(t0_ns, t1_ns)
         self._events.append({
             "name": phase, "ph": "X", "ts": ts, "dur": dur,
             "pid": ENGINE_PID, "tid": 0, "cat": "phase",
@@ -174,9 +205,12 @@ class SpanTracer:
             "args": dict(args or {}), "sid": self._sid()})
 
     def req_chunk(self, rid: int, step: int,
-                  args: dict[str, Any] | None = None) -> None:
-        """One PREFILL(chunk i) sub-span, tiled inside the step's prefill
-        window in execution order."""
+                  args: dict[str, Any] | None = None,
+                  t0_ns: int | None = None,
+                  t1_ns: int | None = None) -> None:
+        """One PREFILL(chunk i) sub-span: tiled inside the step's prefill
+        window in execution order (virtual), or the chunk's real begin
+        ``t0_ns`` and end ``t1_ns`` (wall)."""
         cur_step, k = self._step_chunk_cursor
         if cur_step != step:
             k = 0
@@ -187,7 +221,7 @@ class SpanTracer:
         if self.clock == "virtual":
             ts, dur = self.ts(step, off), _CHUNK_W
         else:
-            ts, dur = self.ts(step), 0
+            ts, dur = self._wall_span(t0_ns, t1_ns)
         self._events.append({
             "name": f"PREFILL(chunk {i})", "ph": "X", "ts": ts, "dur": dur,
             "pid": REQUEST_PID, "tid": rid, "cat": "request",
@@ -259,6 +293,39 @@ class SpanTracer:
         self._chunks = {int(rid): int(n)
                         for rid, n in state["chunks"].items()}
         self._step_chunk_cursor = (-1, 0)
+
+
+class Span(dict):
+    """The counts of one open engine span (written by its body, they become
+    the span's arguments), and its begin and end on the profiler's clock
+    once it has closed."""
+
+    __slots__ = ("t0_ns", "t1_ns")
+
+
+@contextlib.contextmanager
+def engine_span(phase: str, step: int, tracer: SpanTracer | None = None,
+                **counts):
+    """One phase of engine step ``step``: a ``jax.profiler`` annotation
+    ``engine.<phase>`` on the profiler's Python thread line (``engine.step``
+    is the step annotation, numbered ``step``), whose arguments are
+    ``counts`` and whatever the body writes into the yielded :class:`Span`.
+    With a ``tracer`` attached the same span feeds it
+    (:meth:`SpanTracer.step_phase`). An inactive profiler makes the
+    annotation a no-op."""
+    span = Span(counts)
+    if phase == "step":
+        ann = jax.profiler.StepTraceAnnotation("engine.step", step_num=step)
+    else:
+        ann = jax.profiler.TraceAnnotation(f"engine.{phase}")
+    span.t0_ns = time.time_ns()
+    with ann:
+        yield span
+        if span:
+            ann.set_metadata(**span)
+    span.t1_ns = time.time_ns()
+    if tracer is not None:
+        tracer.step_phase(step, phase, dict(span), span.t0_ns, span.t1_ns)
 
 
 # ---------------------------------------------------------------------------

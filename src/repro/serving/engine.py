@@ -75,6 +75,7 @@ FAULT TOLERANCE — the engine degrades per request, never per process:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Any
 
@@ -264,8 +265,10 @@ class ServingEngine:
 
         # prefill trace counter: the wrapped python body runs at TRACE time
         # only, so this counts compiles — the recompile-bound test asserts it
-        # stays <= the bucket count across any mix of prompt lengths
+        # stays <= the bucket count across any mix of prompt lengths. The
+        # wrapper keeps the step's name, which names its jit program
         def _counted(fn):
+            @functools.wraps(fn)
             def wrapper(*args):
                 self._c_prefill_traces.inc()
                 return fn(*args)
@@ -344,13 +347,10 @@ class ServingEngine:
         # on un-evict fast paths, so they cannot be monotonic Counters)
         self.registry.register_collector(self._collect_occupancy)
 
-        # analytic roofline annotation: per-step model bytes/FLOPs for the
-        # decode backend the decode step resolves (ref paged gather models
-        # full-span traffic; kernels stream only visited tokens)
+        # the decode backend the decode step resolves (reported by metrics())
         self._backend = BK.resolve_backend(
             self.cfg.decode_backend, paged=True, batch=ecfg.max_batch,
             n_heads=cfg.n_heads, use_kernels=cfg.use_kernels)
-        self._device_kind = jax.devices()[0].device_kind
 
         # fault tolerance: injection plan, preemption flag, survival metrics
         self.fault_plan = fault_plan
@@ -404,19 +404,6 @@ class ServingEngine:
         self._c_blocks_full = r.counter(
             "snapmla_fetch_decode_blocks_full_total",
             "KV blocks a dense decode sweep would visit")
-        # analytic roofline cost of the dispatched decode work (model, not
-        # measurement: deterministic bytes/FLOPs from the cost annotation)
-        self._c_roof_bytes = r.counter(
-            "snapmla_roofline_model_bytes_total",
-            "modeled HBM bytes moved by the resolved decode backend")
-        self._c_roof_bytes_min = r.counter(
-            "snapmla_roofline_bytes_min_total",
-            "compulsory HBM bytes (visited tokens only)")
-        self._c_roof_flops = r.counter(
-            "snapmla_roofline_flops_total", "modeled attention FLOPs")
-        self._g_roof_frac = r.gauge(
-            "snapmla_roofline_achieved_fraction",
-            "bytes_min / modeled bytes for the last decode dispatch")
         # speculative decoding: drafted-vs-accepted accounting (satellite of
         # the q_len>1 verify path; serving_sim's speculative twin and
         # bench_gate read these through the registry snapshot)
@@ -502,6 +489,11 @@ class ServingEngine:
 
     def _fault(self, kind: str, n: int = 1) -> None:
         self._c_faults.labels(kind=kind).inc(n)
+
+    def _span(self, phase: str, **counts):
+        """``engine.<phase>`` of this step on the profiler's timeline (and
+        the tracer's, when one is attached): ``obs.trace.engine_span``."""
+        return TRC.engine_span(phase, self.step_idx, self.tracer, **counts)
 
     def telemetry(self, *, include_wall: bool = False) -> dict:
         """The registry view (``{"work": ..., "wall": ...}``); the ``work``
@@ -680,17 +672,15 @@ class ServingEngine:
         if not ops:
             return
         assert self.tier is not None, "tier ops without a host tier"
-        if self.tracer:
-            self.tracer.step_phase(self.step_idx, "tier_drain",
-                                   args={"ops": len(ops)})
-        for kind, _pid, slot in ops:
-            if kind == "restore" and self.tier.has_data(slot):
-                self.tier.prefetch(slot)
-        for kind, pid, slot in ops:
-            if kind == "offload":
-                self.tier.store(slot, self._gather_page(pid))
-            else:
-                self._write_page(pid, self.tier.take(slot))
+        with self._span("tier_drain", ops=len(ops)):
+            for kind, _pid, slot in ops:
+                if kind == "restore" and self.tier.has_data(slot):
+                    self.tier.prefetch(slot)
+            for kind, pid, slot in ops:
+                if kind == "offload":
+                    self.tier.store(slot, self._gather_page(pid))
+                else:
+                    self._write_page(pid, self.tier.take(slot))
 
     # ------------------------------------------------------------------
     # sampling + host sync (ONE device_get per call)
@@ -733,7 +723,9 @@ class ServingEngine:
         if counts is None:
             counts = [len(r.out_tokens) for r in reqs]
         counts = jnp.asarray(counts, jnp.int32)
-        toks, finite = jax.device_get(self._post_fn(rows, rids, counts))
+        out = self._post_fn(rows, rids, counts)
+        with self._span("sync"):
+            toks, finite = jax.device_get(out)
         return toks, finite
 
     def _emit(self, req: Request, tok: int) -> None:
@@ -764,18 +756,19 @@ class ServingEngine:
             self.proposer.drop(str(req.rid))
 
     def _retire(self, req: Request) -> None:
-        slot = req.slot
-        self._drop_spec_state(req)
-        self.scheduler.retire(req, self.step_idx, self.allocator)
-        self._wall[req.rid]["finish"] = time.time()
-        if self.tracer:
-            ts = self.tracer.ts(self.step_idx, TRC.OFF_RETIRE)
-            self.tracer.req_end(req.rid, ts, args={"status": "done"})
-            self.tracer.req_instant(req.rid, "DONE", ts,
-                                    args={"tokens": len(req.out_tokens)})
-        if slot >= 0:
-            self.table[slot] = 0          # park the slot on the scratch page
-            self.last_tok[slot] = 0
+        with self._span("retire"):
+            slot = req.slot
+            self._drop_spec_state(req)
+            self.scheduler.retire(req, self.step_idx, self.allocator)
+            self._wall[req.rid]["finish"] = time.time()
+            if self.tracer:
+                ts = self.tracer.ts(self.step_idx, TRC.OFF_RETIRE)
+                self.tracer.req_end(req.rid, ts, args={"status": "done"})
+                self.tracer.req_instant(req.rid, "DONE", ts,
+                                        args={"tokens": len(req.out_tokens)})
+            if slot >= 0:
+                self.table[slot] = 0      # park the slot on the scratch page
+                self.last_tok[slot] = 0
 
     def _requeue(self, req: Request) -> None:
         """Evict-to-requeue: pages freed, generated tokens kept; the request
@@ -956,37 +949,40 @@ class ServingEngine:
         """One bucketed chunk of ``req``'s (effective) prompt through the
         jitted chunk step. Returns the work units spent (padded width)."""
         eff = req.effective_prompt
-        remaining = len(eff) - req.prefill_pos
-        width = min(self.chunk, remaining)
+        pos = req.prefill_pos
+        width = min(self.chunk, len(eff) - pos)
         bucket = ST.bucket_for(width, self.chunk)
-        tok = np.zeros((1, bucket), np.int32)
-        tok[0, :width] = eff[req.prefill_pos:req.prefill_pos + width]
-        view = self._map_pools(
-            lambda pool: pool_with_tables(
-                pool, self.table[req.slot][None],
-                np.asarray([req.prefill_pos], np.int32)), self.state)
-        t0 = time.time()
-        logits, new_state = self._chunk_fn(
-            self.params, jnp.asarray(tok), view,
-            jnp.asarray([req.prefill_pos], jnp.int32),
-            jnp.asarray([width - 1], jnp.int32))
-        logits.block_until_ready()
-        self._w_prefill_s.inc(time.time() - t0)
-        self._adopt_pool_data(new_state)
-        # bounded prefix fetch reads ceil(chunk_start / page) pages — the
-        # live prefix BELOW this chunk's start — where the full-span fetch
-        # would stream the whole page-table span every chunk
-        self._c_fetch_bounded.inc(-(-req.prefill_pos // self.page))
-        self._c_fetch_full.inc(self.span_pages)
-        self._h_chunk_width.observe(bucket)
+        with self._span("chunk", width=width, pos=pos) as span:
+            tok = np.zeros((1, bucket), np.int32)
+            tok[0, :width] = eff[pos:pos + width]
+            view = self._map_pools(
+                lambda pool: pool_with_tables(
+                    pool, self.table[req.slot][None],
+                    np.asarray([pos], np.int32)), self.state)
+            t0 = time.time()
+            logits, new_state = self._chunk_fn(
+                self.params, jnp.asarray(tok), view,
+                jnp.asarray([pos], jnp.int32),
+                jnp.asarray([width - 1], jnp.int32))
+            with self._span("sync"):
+                logits.block_until_ready()
+            self._w_prefill_s.inc(time.time() - t0)
+            self._adopt_pool_data(new_state)
+            # bounded prefix fetch reads ceil(chunk_start / page) pages — the
+            # live prefix BELOW this chunk's start — where the full-span
+            # fetch would stream the whole page-table span every chunk
+            self._c_fetch_bounded.inc(-(-pos // self.page))
+            self._c_fetch_full.inc(self.span_pages)
+            self._h_chunk_width.observe(bucket)
+            req.prefill_pos += width
+            self.allocator.mark_ready(req.pages, req.prefill_pos)
+            if req.prefill_pos == len(eff):
+                self._finish_prefill(req, logits)
         if self.tracer:
             self.tracer.req_chunk(req.rid, self.step_idx,
                                   args={"width": width, "bucket": bucket,
-                                        "pos": req.prefill_pos})
-        req.prefill_pos += width
-        self.allocator.mark_ready(req.pages, req.prefill_pos)
-        if req.prefill_pos == len(eff):
-            self._finish_prefill(req, logits)
+                                        "pos": pos},
+                                  t0_ns=span.t0_ns, t1_ns=span.t1_ns)
         return bucket
 
     def _prefill_chunked(self) -> int:
@@ -1025,7 +1021,8 @@ class ServingEngine:
                 self.state)
             t0 = time.time()
             logits, new_state = self._prefill_fn(self.params, prompts, view)
-            logits.block_until_ready()
+            with self._span("sync"):
+                logits.block_until_ready()
             self._w_prefill_s.inc(time.time() - t0)
             self._adopt_pool_data(new_state)
             self._h_chunk_width.observe(length)
@@ -1185,108 +1182,105 @@ class ServingEngine:
         samples land per step."""
         e = self.ecfg
         K = e.spec_draft_len + 1
-        tokens = np.zeros((e.max_batch, K), np.int32)
-        starts = np.zeros((e.max_batch,), np.int32)
-        table_view = np.zeros_like(self.table)
-        drafts: dict[int, list[int]] = {}
-        for r in active:
-            # trim the draft to what the slot can actually use: committed
-            # entries land at seq_len..seq_len+v (v+1 of them), the run is
-            # bounded by allocated pages, and drafting past max_new-1 new
-            # tokens is wasted work
-            budget = min(e.spec_draft_len,
-                         r.max_new - len(r.out_tokens) - 1,
-                         len(r.pages) * self.page - r.seq_len - 1)
-            d: list[int] = []
-            if budget > 0:
-                ctx = [int(t) for t in r.prompt] + list(r.out_tokens)
-                d = self.proposer.propose(str(r.rid), ctx, budget)
-            drafts[r.rid] = d
-            row = [int(self.last_tok[r.slot])] + d
-            tokens[r.slot, :len(row)] = row
-            starts[r.slot] = r.seq_len
-            table_view[r.slot] = self.table[r.slot]
-        state = self._state_with_tables(table_view, starts)
-        t0 = time.time()
-        logits, self.state = self._dispatch_verify(
-            state, jnp.asarray(tokens), jnp.asarray(starts))
-        if self.fault_plan:
-            live = {r.slot for r in active}
-            for ev in self.fault_plan.nan_slots(self.step_idx):
-                if ev.slot in live:
-                    self.fault_plan._log(self.step_idx, "nan_logits",
-                                         ev.slot)
-                    logits = logits.at[ev.slot, 0, 0].set(jnp.nan)
-        # flatten to one postprocess row per CANDIDATE (slot, position):
-        # counts advance by position so the sampling keys are exactly the
-        # sequential ones
-        flat_reqs: list[Request] = []
-        flat_counts: list[int] = []
-        sel_slots: list[int] = []
-        sel_pos: list[int] = []
-        for r in active:
-            for t in range(len(drafts[r.rid]) + 1):
-                flat_reqs.append(r)
-                flat_counts.append(len(r.out_tokens) + t)
-                sel_slots.append(r.slot)
-                sel_pos.append(t)
-        rows = logits[np.asarray(sel_slots), np.asarray(sel_pos)]
-        toks, finite = self._postprocess(rows, flat_reqs, counts=flat_counts)
-        self._w_decode_s.inc(time.time() - t0)
+        with self._span("decode", verify=1, rows=len(active),
+                        q_len=K) as span:
+            tokens = np.zeros((e.max_batch, K), np.int32)
+            starts = np.zeros((e.max_batch,), np.int32)
+            table_view = np.zeros_like(self.table)
+            drafts: dict[int, list[int]] = {}
+            for r in active:
+                # trim the draft to what the slot can actually use:
+                # committed entries land at seq_len..seq_len+v (v+1 of
+                # them), the run is bounded by allocated pages, and drafting
+                # past max_new-1 new tokens is wasted work
+                budget = min(e.spec_draft_len,
+                             r.max_new - len(r.out_tokens) - 1,
+                             len(r.pages) * self.page - r.seq_len - 1)
+                d: list[int] = []
+                if budget > 0:
+                    ctx = [int(t) for t in r.prompt] + list(r.out_tokens)
+                    d = self.proposer.propose(str(r.rid), ctx, budget)
+                drafts[r.rid] = d
+                row = [int(self.last_tok[r.slot])] + d
+                tokens[r.slot, :len(row)] = row
+                starts[r.slot] = r.seq_len
+                table_view[r.slot] = self.table[r.slot]
+            # every verify row t reads the slot's context up to its own
+            # limit seq_len + t + 1
+            visited = [r.seq_len + t + 1 for r in active for t in range(K)]
+            span.update(drafted=sum(len(d) for d in drafts.values()),
+                        ctx_tokens=sum(visited))
+            state = self._state_with_tables(table_view, starts)
+            t0 = time.time()
+            logits, self.state = self._dispatch_verify(
+                state, jnp.asarray(tokens), jnp.asarray(starts))
+            if self.fault_plan:
+                live = {r.slot for r in active}
+                for ev in self.fault_plan.nan_slots(self.step_idx):
+                    if ev.slot in live:
+                        self.fault_plan._log(self.step_idx, "nan_logits",
+                                             ev.slot)
+                        logits = logits.at[ev.slot, 0, 0].set(jnp.nan)
+            # deterministic work/traffic accounting
+            self._c_blocks_visited.inc(int(sum(-(-n // self.page)
+                                               for n in visited)))
+            self._c_blocks_full.inc(len(active) * K * self.span_pages)
 
-        # deterministic work/traffic accounting: every verify row visits
-        # blocks up to its own per-row limit (seq_len + t + 1)
-        self._c_blocks_visited.inc(int(sum(
-            -(-(r.seq_len + t + 1) // self.page)
-            for r in active for t in range(K))))
-        self._c_blocks_full.inc(len(active) * K * self.span_pages)
-        cost = BK.dispatch_cost(
-            self._backend,
-            tokens_visited=sum(r.seq_len + t + 1
-                               for r in active for t in range(K)),
-            tokens_full=len(active) * K * self.span_pages * self.page,
-            heads=self.cfg.n_heads, d_c=self.cfg.mla.d_c,
-            d_r=self.cfg.mla.d_rope, fmt=self.cfg.kv_fmt,
-            device_kind=self._device_kind)
-        self._c_roof_bytes.inc(cost["bytes"])
-        self._c_roof_bytes_min.inc(cost["bytes_min"])
-        self._c_roof_flops.inc(cost["flops"])
-        self._g_roof_frac.set(cost["achieved_fraction"])
+        with self._span("sample", verify=1) as span:
+            # flatten to one postprocess row per CANDIDATE (slot,
+            # position): counts advance by position so the sampling keys
+            # are exactly the sequential ones
+            flat_reqs: list[Request] = []
+            flat_counts: list[int] = []
+            sel_slots: list[int] = []
+            sel_pos: list[int] = []
+            for r in active:
+                for t in range(len(drafts[r.rid]) + 1):
+                    flat_reqs.append(r)
+                    flat_counts.append(len(r.out_tokens) + t)
+                    sel_slots.append(r.slot)
+                    sel_pos.append(t)
+            rows = logits[np.asarray(sel_slots), np.asarray(sel_pos)]
+            toks, finite = self._postprocess(rows, flat_reqs,
+                                             counts=flat_counts)
+            self._w_decode_s.inc(time.time() - t0)
 
-        # longest-accepted-prefix commit: emit the exact sequential samples
-        # while each drafted token matches; stop at the first mismatch (its
-        # corrective sample still lands — the guaranteed one-token floor),
-        # at retirement (EOS/max_new), or at a non-finite row (sequential
-        # quarantine semantics at the already-advanced position)
-        idx = 0
-        n_drafted = n_accepted = n_emitted = 0
-        for r in active:
-            d = drafts[r.rid]
-            v = len(d)
-            committed = 0
-            bad = False
-            for j in range(v + 1):
-                fi = idx + j
-                if not finite[fi]:
-                    bad = True
+            # longest-accepted-prefix commit: emit the exact sequential
+            # samples while each drafted token matches; stop at the first
+            # mismatch (its corrective sample still lands — the guaranteed
+            # one-token floor), at retirement (EOS/max_new), or at a
+            # non-finite row (sequential quarantine semantics at the
+            # already-advanced position)
+            idx = 0
+            n_drafted = n_accepted = n_emitted = 0
+            for r in active:
+                d = drafts[r.rid]
+                v = len(d)
+                committed = 0
+                bad = False
+                for j in range(v + 1):
+                    fi = idx + j
+                    if not finite[fi]:
+                        bad = True
+                        break
+                    tok = int(toks[fi])
+                    self._emit(r, tok)
+                    n_emitted += 1
+                    committed += 1
+                    if r.status is not Status.DECODE:
+                        break
+                    if j < v and tok == d[j]:
+                        continue
                     break
-                tok = int(toks[fi])
-                self._emit(r, tok)
-                n_emitted += 1
-                committed += 1
-                if r.status is not Status.DECODE:
-                    break
-                if j < v and tok == d[j]:
-                    continue
-                break
-            idx += v + 1
-            accepted = max(committed - 1, 0)
-            n_drafted += v
-            n_accepted += accepted
-            if bad:
-                self._quarantine(r)
-            elif r.status is Status.DECODE:
-                self.proposer.observe(str(r.rid), v, accepted)
+                idx += v + 1
+                accepted = max(committed - 1, 0)
+                n_drafted += v
+                n_accepted += accepted
+                if bad:
+                    self._quarantine(r)
+                elif r.status is Status.DECODE:
+                    self.proposer.observe(str(r.rid), v, accepted)
+            span.update(rows=len(flat_reqs), accepted=n_accepted)
 
         self._c_decode_tokens.inc(n_emitted)
         self._c_work.inc(n_emitted)
@@ -1298,62 +1292,20 @@ class ServingEngine:
         self._g_spec_accept_rate.set(
             self._c_spec_accepted.value / drafted_total
             if drafted_total else 0.0)
-        if self.tracer:
-            # verify spans ride the decode phase window (args mark them)
-            self.tracer.step_phase(
-                self.step_idx, "decode",
-                args={"verify": True, "rows": len(active), "q_len": K,
-                      "drafted": n_drafted, "accepted": n_accepted,
-                      "model_bytes": cost["bytes"],
-                      "achieved_fraction": cost["achieved_fraction"]})
-            self.tracer.step_phase(self.step_idx, "postprocess",
-                                   args={"rows": len(flat_reqs)})
 
-    def step(self) -> None:
-        """One engine iteration: sweep deadlines, admit, run (budgeted)
-        prefill work, grow, one decode step for every decoding slot, retire
-        finished requests. Advances virtual time even when idle (so future
-        arrivals are reached)."""
-        self._sweep_deadlines()
-        decode_in_flight = any(r.status is Status.DECODE
-                               for r in self.scheduler.active)
-        finished_before = len(self.scheduler.finished)
-        admitted = self._admit()
-        if self.tracer and admitted:
-            self.tracer.step_phase(self.step_idx, "admit",
-                                   args={"admitted": len(admitted)})
-        t_pre = time.time()
-        if self.chunk > 0:
-            spent = self._prefill_chunked()
-        else:
-            spent = self._prefill_monolithic(admitted)
-        self._c_prefill_tokens.inc(spent)
-        self._c_work.inc(spent)
-        self.prefill_tokens_series.append(spent)
-        # decode-stall accounting: prefill work that ran while decodes were
-        # in flight is exactly the work that would have stalled them
-        self.stall_tokens_series.append(spent if decode_in_flight else 0)
-        if decode_in_flight:
-            self._w_stall_s.inc(time.time() - t_pre)
-        if self.tracer and spent:
-            self.tracer.step_phase(self.step_idx, "prefill",
-                                   args={"tokens": spent,
-                                         "stalled_decodes": decode_in_flight})
-
-        self._ensure_capacity()
-        # growth-pressure evictions may have queued offloads: copy those
-        # pages' bytes out before the decode dispatch can overwrite them
-        self._drain_tier_ops()
-        active = [r for r in self.scheduler.active
-                  if r.status is Status.DECODE]
-        if active and self.proposer is not None:
-            self._spec_decode(active)
-        elif active:
+    def _decode(self, active: list[Request]) -> None:
+        """One-token decode for every decoding slot: one jitted dispatch,
+        then one postprocess whose single host transfer brings every row's
+        token and finite flag back."""
+        with self._span("decode", rows=len(active)) as span:
             seq_lens = np.zeros((self.ecfg.max_batch,), np.int32)
             table_view = np.zeros_like(self.table)
             for r in active:
                 seq_lens[r.slot] = r.seq_len
                 table_view[r.slot] = self.table[r.slot]
+            # the context each row's attention reads: its resident tokens
+            # and the one this step appends
+            span["ctx_tokens"] = int(seq_lens.sum()) + len(active)
             state = self._state_with_tables(table_view, seq_lens)
             t0 = time.time()
             logits, self.state = self._dispatch_decode(state, seq_lens)
@@ -1368,38 +1320,18 @@ class ServingEngine:
                         self.fault_plan._log(self.step_idx, "nan_logits",
                                              ev.slot)
                         logits = logits.at[ev.slot, 0].set(jnp.nan)
-            slots = np.array([r.slot for r in active], np.int32)
             # split-KV early exit: each row visits ceil(seq_len / page)
             # blocks; a dense decode would sweep the full span per row
             self._c_blocks_visited.inc(int(
                 sum(-(-r.seq_len // self.page) for r in active)))
             self._c_blocks_full.inc(len(active) * self.span_pages)
-            # analytic roofline annotation of this dispatch (model, not
-            # measurement: pure function of the visited-token counts)
-            cost = BK.dispatch_cost(
-                self._backend,
-                tokens_visited=sum(r.seq_len for r in active),
-                tokens_full=len(active) * self.span_pages * self.page,
-                heads=self.cfg.n_heads, d_c=self.cfg.mla.d_c,
-                d_r=self.cfg.mla.d_rope, fmt=self.cfg.kv_fmt,
-                device_kind=self._device_kind)
-            self._c_roof_bytes.inc(cost["bytes"])
-            self._c_roof_bytes_min.inc(cost["bytes_min"])
-            self._c_roof_flops.inc(cost["flops"])
-            self._g_roof_frac.set(cost["achieved_fraction"])
-            if self.tracer:
-                self.tracer.step_phase(
-                    self.step_idx, "decode",
-                    args={"rows": len(active),
-                          "model_bytes": cost["bytes"],
-                          "achieved_fraction": cost["achieved_fraction"]})
+
+        with self._span("sample", rows=len(active)):
+            slots = np.array([r.slot for r in active], np.int32)
             toks, finite = self._postprocess(logits[slots], active)
             self._w_decode_s.inc(time.time() - t0)
             self._c_decode_tokens.inc(len(active))
             self._c_work.inc(len(active))
-            if self.tracer:
-                self.tracer.step_phase(self.step_idx, "postprocess",
-                                       args={"rows": len(active)})
             for r, tok, ok in zip(active, toks, finite):
                 if not ok:
                     # per-slot quarantine: THIS request degrades (ref retry
@@ -1407,26 +1339,69 @@ class ServingEngine:
                     self._quarantine(r)
                     continue
                 self._emit(r, int(tok))
-        live = sum(r.seq_len if r.status is Status.DECODE else r.prefill_pos
-                   for r in self.scheduler.active)
-        self.util_series.append(self.allocator.stats(live).utilization)
-        if self.tracer:
-            retired = len(self.scheduler.finished) - finished_before
-            if retired:
-                self.tracer.step_phase(self.step_idx, "retire",
-                                       args={"requests": retired})
-            a = self.allocator
-            self.tracer.counter(self.step_idx, "pages",
-                                {"in_use": a.num_in_use, "free": a.num_free,
-                                 "cached": a.num_cached})
-        if self.quant_probe and self.quant_probe.due(self.step_idx):
-            self.quant_probe.sample(
-                self.step_idx, self._map_pools, self.state,
-                resident_pages=self.allocator.resident_pages(),
-                sink_pages={r.pages[0] for r in self.scheduler.active
-                            if r.pages})
-        self._c_steps.inc()
-        self.step_idx += 1
+
+    def step(self) -> None:
+        """One engine iteration: sweep deadlines, admit, run (budgeted)
+        prefill work, grow, one decode step for every decoding slot, retire
+        finished requests. Advances virtual time even when idle (so future
+        arrivals are reached). The step and each of its phases are spans
+        (``_span``) on the profiler's timeline."""
+        with self._span("step"):
+            self._sweep_deadlines()
+            decode_in_flight = any(r.status is Status.DECODE
+                                   for r in self.scheduler.active)
+            with self._span("admit") as span:
+                admitted = self._admit()
+                span["admitted"] = len(admitted)
+            spent = 0
+            prefilling = (self.scheduler.prefilling if self.chunk > 0
+                          else admitted)
+            if prefilling:
+                with self._span("prefill") as span:
+                    t_pre = time.time()
+                    spent = (self._prefill_chunked() if self.chunk > 0
+                             else self._prefill_monolithic(admitted))
+                    if decode_in_flight:
+                        self._w_stall_s.inc(time.time() - t_pre)
+                    span.update(tokens=spent,
+                                stalled_decodes=int(decode_in_flight))
+            self._c_prefill_tokens.inc(spent)
+            self._c_work.inc(spent)
+            self.prefill_tokens_series.append(spent)
+            # decode-stall accounting: prefill work that ran while decodes
+            # were in flight is exactly the work that would have stalled
+            # them
+            self.stall_tokens_series.append(spent if decode_in_flight else 0)
+
+            with self._span("grow"):
+                self._ensure_capacity()
+            # growth-pressure evictions may have queued offloads: copy those
+            # pages' bytes out before the decode dispatch can overwrite them
+            self._drain_tier_ops()
+            active = [r for r in self.scheduler.active
+                      if r.status is Status.DECODE]
+            if active and self.proposer is not None:
+                self._spec_decode(active)
+            elif active:
+                self._decode(active)
+            live = sum(r.seq_len if r.status is Status.DECODE
+                       else r.prefill_pos for r in self.scheduler.active)
+            self.util_series.append(self.allocator.stats(live).utilization)
+            if self.tracer:
+                a = self.allocator
+                self.tracer.counter(self.step_idx, "pages",
+                                    {"in_use": a.num_in_use,
+                                     "free": a.num_free,
+                                     "cached": a.num_cached})
+            if self.quant_probe and self.quant_probe.due(self.step_idx):
+                with self._span("quant_probe"):
+                    self.quant_probe.sample(
+                        self.step_idx, self._map_pools, self.state,
+                        resident_pages=self.allocator.resident_pages(),
+                        sink_pages={r.pages[0] for r in self.scheduler.active
+                                    if r.pages})
+            self._c_steps.inc()
+            self.step_idx += 1
 
     # ------------------------------------------------------------------
     # checkpoint / restore (host bookkeeping + device pool pages)
@@ -1600,7 +1575,6 @@ class ServingEngine:
         stats = self.allocator.stats()
         tps = self.decode_tokens / self.decode_seconds \
             if self.decode_seconds else 0.0
-        roof_bytes = self._c_roof_bytes.value
         return {
             "steps": self.step_idx,
             "decode_tokens": self.decode_tokens,
@@ -1628,16 +1602,7 @@ class ServingEngine:
                 "stall_tokens_total": int(sum(self.stall_tokens_series)),
                 "stall_tokens_series": self.stall_tokens_series,
             },
-            "roofline": {
-                "backend": self._backend.name,
-                "model_bytes": roof_bytes,
-                "bytes_min": self._c_roof_bytes_min.value,
-                "flops": self._c_roof_flops.value,
-                "achieved_fraction_total": (
-                    self._c_roof_bytes_min.value / roof_bytes
-                    if roof_bytes else 0.0),
-                "achieved_fraction_last": self._g_roof_frac.value,
-            },
+            "decode_backend": self._backend.name,
             "fetch_work": {
                 "pages_fetched_bounded": self.pages_fetched_bounded,
                 "pages_fetched_full": self.pages_fetched_full,

@@ -6,11 +6,16 @@ present, and each test checks that the compiled program holds the kernel
 (``tpu_custom_call``). Interpret mode accepts block shapes and vector ops the
 chip's compiler refuses; these compiles catch that without chip time.
 
+Each kernel's custom call carries the kernel's own name (``name=`` on its
+``pallas_call``), not the name of the jitted function around it: the device
+trace names its ops after these instructions.
+
 The topology is described inside the fixture only (never at import): one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +64,15 @@ def _compile(one_chip, fn, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernels(text):
+    """The names of the compiled program's Pallas kernels (its TPU custom
+    calls), each without its numeric suffix, as the device trace's op names
+    read."""
+    return {re.sub(r"[.\-_]\d+$", "", m) for m in re.findall(
+        r"^\s*(?:ROOT )?%?(\S+) = .* custom-call\(.*"
+        r'custom_call_target="tpu_custom_call"', text, re.M)}
+
+
 def _pool_shapes(rope_dtype=F32):
     return [((N_PAGES, PAGE, D_C), F8), ((N_PAGES, PAGE, D_R), rope_dtype),
             ((N_PAGES, PAGE), F32), ((B, P), I32), ((B,), I32)]
@@ -81,6 +95,9 @@ def test_paged_splitkv_decode_compiles(one_chip, q_len, rescale):
     text = _compile(one_chip, fn, (q + (D_C,), F8), (q + (D_R,), F32),
                     (q, F32), c, r, s, pt, sl)
     assert "tpu_custom_call" in text
+    assert _kernels(text) == {"mla_decode_paged_splitkv",
+                              f"{'lse' if rescale == 'fma' else 'amla'}"
+                              "_combine"}
     # every pool operand, scales included, is read by the page-table index
     # maps: no XLA gather of the pool runs beside the kernel
     assert "gather" not in text
@@ -106,6 +123,7 @@ def test_contiguous_splitkv_decode_compiles(one_chip, q_len, rescale):
 
     text = _compile(one_chip, fn, *_contiguous_shapes(q_len))
     assert "tpu_custom_call" in text
+    assert "mla_decode_splitkv" in _kernels(text)
 
 
 @pytest.mark.parametrize("rescale", ["fma", "amla"])
@@ -119,6 +137,7 @@ def test_contiguous_single_pass_decode_compiles(one_chip, rescale):
 
     text = _compile(one_chip, fn, *_contiguous_shapes(1))
     assert "tpu_custom_call" in text
+    assert _kernels(text) == {"mla_decode"}
 
 
 def test_paged_single_pass_decode_compiles(one_chip):
@@ -131,6 +150,7 @@ def test_paged_single_pass_decode_compiles(one_chip):
     text = _compile(one_chip, fn, ((B, H, D_C), F8), ((B, H, D_R), F32),
                     ((B, H), F32), *_pool_shapes()[:4], ((B,), I32))
     assert "tpu_custom_call" in text
+    assert _kernels(text) == {"mla_decode_paged"}
     assert "gather" not in text
 
 
@@ -144,6 +164,7 @@ def test_split_combine_compiles(one_chip, combine):
         text = _compile(one_chip, lambda o, l, g: K.amla_combine_pallas(
             o, l, g, interpret=False), ((B, SPLITS, H, D_C), F32), vec, vec)
     assert "tpu_custom_call" in text
+    assert _kernels(text) == {f"{combine}_combine"}
 
 
 @pytest.mark.parametrize("bounded", [True, False])
@@ -159,6 +180,7 @@ def test_paged_fetch_dequant_compiles(one_chip, bounded):
 
     text = _compile(one_chip, fn, *_pool_shapes(BF16), ((B,), I32))
     assert "tpu_custom_call" in text
+    assert _kernels(text) == {"fetch_dequant"}
     assert "gather" not in text
 
 
@@ -174,6 +196,7 @@ def test_contiguous_fetch_dequant_compiles(one_chip):
     text = _compile(one_chip, fn, ((B, n, D_C), F8), ((B, n, D_R), BF16),
                     ((B, n), F32), ((B,), I32))
     assert "tpu_custom_call" in text
+    assert _kernels(text) == {"fetch_dequant"}
 
 
 def test_quantize_kernels_compile(one_chip):
@@ -187,3 +210,5 @@ def test_quantize_kernels_compile(one_chip):
         ((B, n, D_C), F8), ((B, n, D_R), BF16), ((B, n), F32),
         ((B, D_C), F32), ((B, D_R), F32), ((B,), I32))
     assert "tpu_custom_call" in q_text and "tpu_custom_call" in k_text
+    assert _kernels(q_text) == {"quantize_query"}
+    assert _kernels(k_text) == {"quantize_append"}

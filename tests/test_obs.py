@@ -211,6 +211,55 @@ def test_quant_probe_sees_resident_fp8_pages(model):
     assert all(0.0 <= s["clip_rate_max"] <= 1.0 for s in mid)
 
 
+def test_wall_clock_spans_nest_and_line_up_with_the_profiler(model,
+                                                             tmp_path):
+    """``clock="wall"``: every step phase has a real, positive duration
+    inside its step, and each step starts where the profiler's own
+    ``engine.step`` of the same step does (both on the epoch clock the
+    profile starts from), within 1 ms."""
+    from jax.profiler import ProfileData
+    cfg, params = model
+    reqs, S, gen = _workload(cfg)
+    tracer = SpanTracer(clock="wall")
+    engine = _engine(cfg, params, S, gen, tracer=tracer)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        engine.run(reqs)
+    finally:
+        jax.profiler.stop_trace()
+    payload = tracer.chrome_payload()
+    validate_chrome_trace(payload, expect_requests=len(reqs))
+    phases = [e for e in payload["traceEvents"]
+              if e.get("cat") == "phase"]
+    steps = {e["args"]["step"]: e for e in phases if e["name"] == "step"}
+    assert sorted(steps) == list(range(engine.step_idx))
+    inner = [e for e in phases if e["name"] != "step"]
+    assert {e["name"] for e in inner} >= {"admit", "prefill", "chunk",
+                                          "grow", "decode", "sample",
+                                          "sync", "retire"}
+    for e in phases:
+        assert e["dur"] > 0, e
+    for e in inner:
+        st = steps[e["args"]["step"]]
+        assert st["ts"] <= e["ts"] and \
+            e["ts"] + e["dur"] <= st["ts"] + st["dur"], e
+
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    start_ns = next(dict(p.stats)["profile_start_time"] for p in pd.planes
+                    if p.name == "Task Environment")
+    prof = {}
+    for plane in pd.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                args = dict(ev.stats)
+                if ev.name == "engine.step" and "step_num" in args:
+                    prof[args["step_num"]] = start_ns + ev.start_ns
+    assert sorted(prof) == sorted(steps)
+    for step, e in steps.items():
+        assert abs(e["ts"] - prof[step] / 1000) < 1000, step
+
+
 # ---------------------------------------------------------------------------
 # checkpoint -> restore -> resume: one contiguous trace
 # ---------------------------------------------------------------------------
